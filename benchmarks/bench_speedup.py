@@ -5,9 +5,11 @@ container has ONE physical core, so wall-clock multi-device scaling is
 unmeasurable by construction):
 
   1. device-count sweep of the *sharded* pipeline (1..8 forced host
-     devices, subprocess-isolated): reports per-device work (local scan
+     devices, subprocess-isolated and pinned to the CPU, since the
+     parent holds any accelerator): reports per-device work (local scan
      columns) and the collective bytes that the extra devices cost —
-     the communication/computation trade the paper's fig. 3 embodies;
+     the communication/computation trade the paper's fig. 3 embodies.
+     These rows are CPU simulations (``cpu-sim`` in their names);
   2. lazy-pop overhead (pops / inserts) vs n — the paper's argument for
      why HEAP-TMFG scales: constant near-1 revalidation overhead.
 """
@@ -54,6 +56,7 @@ def run(scale: float = 1.0, device_counts=(1, 2, 4, 8)):
     rows = []
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"       # never contend for the held chip
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     base_sum = None
     for d in device_counts:
@@ -61,14 +64,13 @@ def run(scale: float = 1.0, device_counts=(1, 2, 4, 8)):
                               capture_output=True, text=True, env=env,
                               timeout=900)
         if proc.returncode != 0:
-            rows.append(dict(name=f"fig3/devices={d}", us_per_call="",
-                             derived="FAILED"))
-            continue
+            raise RuntimeError(f"{d}-device CPU-simulation child failed: "
+                               f"{proc.stderr[-400:]}")
         rec = json.loads(proc.stdout.strip().splitlines()[-1])
         if base_sum is None:
             base_sum = rec["edge_sum"]
         rows.append(dict(
-            name=f"fig3/devices={d}",
+            name=f"fig3/cpu-sim-devices={d}",
             us_per_call=f"{rec['wall'] * 1e6:.0f}",
             derived=f"cols_per_device={rec['cols_per_device']}",
             wall_s=f"{rec['wall']:.3f}",

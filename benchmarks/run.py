@@ -22,7 +22,10 @@ columns).  Sections:
 
 ``--strict`` turns section failures into a nonzero exit code (CI);
 ``--json`` writes every section's rows to one JSON file (the CI
-artifact).  Without ``--strict`` failures print and the run continues.
+artifact).  Without ``--strict`` failures print and the run continues,
+and the exit code is 0 only off the chip: on a TPU any failed section
+exits nonzero.  The persistent compile cache is set up at start
+(``core/jitcache.use_persistent_cache``).
 
 ``--check-schema`` enforces the observability row contract (DESIGN.md
 §15.4): every row of every run section carries a non-empty
@@ -186,6 +189,9 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
 
+    import jax
+    on_chip = jax.devices()[0].platform == "tpu"
+
     results, failed = {}, []
     for name in only:
         print(f"\n===== {name} =====")
@@ -215,10 +221,13 @@ def main(argv=None) -> int:
 
     if failed:
         print(f"# FAILED sections: {','.join(failed)}", file=sys.stderr)
-        if args.strict:
+        if args.strict or on_chip:
             return 1
     return 0
 
 
 if __name__ == "__main__":
+    from repro.core import jitcache
+    print(f"# compile cache: {jitcache.use_persistent_cache()}",
+          file=sys.stderr)
     sys.exit(main())

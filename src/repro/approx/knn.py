@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.kernels import ops
-from repro.kernels.ref import standardize_rows
+from repro.kernels.ref import SIM_PRECISION, standardize_rows
 
 NEG = -jnp.inf
 
@@ -124,7 +124,8 @@ def densify(table: TopKTable, *, n: int) -> jax.Array:
 def _rescore(X, pools, k: int):
     Z = standardize_rows(X)                                  # (n, L)
     cand = Z[pools]                                          # (n, P, L)
-    s = jnp.clip(jnp.einsum("nl,npl->np", Z, cand), -1.0, 1.0)
+    s = jnp.clip(jnp.einsum("nl,npl->np", Z, cand,
+                            precision=SIM_PRECISION), -1.0, 1.0)
     n, P = s.shape
     s = jnp.where(pools == jnp.arange(n, dtype=pools.dtype)[:, None],
                   NEG, s)                                    # drop self

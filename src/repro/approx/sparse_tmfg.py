@@ -43,6 +43,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.tmfg import NEG, TMFGResult, _State
+from repro.kernels.ref import SIM_PRECISION
 
 from .knn import TopKTable
 
@@ -75,7 +76,8 @@ def _true_row(src, from_x: bool, v):
     dense-row fallback's operand.  O(n·L) from the standardized series
     (never an (n, n) buffer), or a gather when S is the source."""
     if from_x:
-        row = jnp.clip(src @ src[v], -1.0, 1.0)
+        row = jnp.clip(jnp.dot(src, src[v], precision=SIM_PRECISION),
+                       -1.0, 1.0)
         return row.at[v].set(NEG)
     return src[v]                       # from-S source has NEG diagonal
 
@@ -86,7 +88,8 @@ def _pair_value(src, from_x: bool, topv, topi, u, w):
     pos = jnp.argmax(tk == w)
     hit = tk[pos] == w
     if from_x:
-        fb = jnp.clip(jnp.dot(src[u], src[w]), -1.0, 1.0)
+        fb = jnp.clip(jnp.dot(src[u], src[w], precision=SIM_PRECISION),
+                      -1.0, 1.0)
     else:
         fb = src[u, w]
     return jnp.where(hit, topv[u, pos], fb), hit

@@ -189,7 +189,7 @@ def build_tmfg_sharded(S: jax.Array, mesh: Mesh, *, axis="data",
             st = _lazy_loop_sharded(st, lookup, gather, n)
         return _result_of(st)
 
-    out = dist_sh.shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh, in_specs=dist_sh.timeseries_spec(axis),
         out_specs=jax.tree.map(lambda _: P(), _result_spec(n)),
         check_vma=False,
@@ -527,7 +527,7 @@ def apsp_hub_sharded(W: jax.Array, mesh: Mesh, *, axis="data",
         est = jnp.minimum(est, W_local)
         return est
 
-    est = dist_sh.shard_map(fn, mesh=mesh,
+    est = jax.shard_map(fn, mesh=mesh,
                         in_specs=(dist_sh.timeseries_spec(axis), P()),
                         out_specs=dist_sh.timeseries_spec(axis),
                         check_vma=False)(W, D_h0)
@@ -537,6 +537,15 @@ def apsp_hub_sharded(W: jax.Array, mesh: Mesh, *, axis="data",
 # ---------------------------------------------------------------------------
 # the config-driven multi-device funnel (DESIGN.md §17.4)
 # ---------------------------------------------------------------------------
+
+def _replicated(fn, mesh: Mesh):
+    """``fn`` run whole on every device of ``mesh``, inputs and outputs
+    replicated.  XLA cannot partition a Pallas kernel, so the stages
+    that run replicated go through ``shard_map`` like the sharded ones:
+    each device runs its own copy of the kernels on local data."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
+
 
 def run_pipeline_sharded(X_or_S, config: PipelineConfig, mesh: Mesh, *,
                          axis="data", is_similarity: Optional[bool] = None,
@@ -556,7 +565,7 @@ def run_pipeline_sharded(X_or_S, config: PipelineConfig, mesh: Mesh, *,
       * dense similarity — row-sharded Pearson, column-sharded TMFG
         construction, row-sharded hub APSP (or exact/replicated below
         ``HUB_MIN_N``, matching the single-device dispatcher), then the
-        device DBHT core.
+        device DBHT core, all in one jitted program.
       * ``apsp_method="sparse"`` or topk-from-S — the fused single-jit
         program on the materialized input (GSPMD places it); there is
         no cross-device structure left to exploit by hand.
@@ -581,7 +590,8 @@ def run_pipeline_sharded(X_or_S, config: PipelineConfig, mesh: Mesh, *,
 
     if cfg.similarity == "topk" and not is_similarity:
         kk = min(cfg.sim_k, n - 1)
-        v, i, z = dist_sh.topk_pearson_sharded(arr, kk, mesh, axis=axis)
+        v, i, z = dist_sh.topk_pearson_sharded(arr, kk, mesh, axis=axis,
+                                               backend=cfg.backend)
 
         def build():
             from repro.core import fused_approx as fa
@@ -597,11 +607,9 @@ def run_pipeline_sharded(X_or_S, config: PipelineConfig, mesh: Mesh, *,
                     linkage=core["Z"], hubs=core["hubs"],
                     overflow=core["overflow"], counters=core["counters"])
 
-            return jax.jit(whole)
+            return jax.jit(_replicated(whole, mesh))
 
-        fn = jitcache.cached(
-            ("sharded_tail", cfg, n, kk, caps,
-             tuple(str(d) for d in mesh.devices.flat)), build)
+        fn = jitcache.cached(("sharded_tail", cfg, n, kk, caps, mesh), build)
         return fn(v, i, z)
 
     if cfg.similarity == "topk" or cfg.apsp_method == "sparse":
@@ -610,16 +618,11 @@ def run_pipeline_sharded(X_or_S, config: PipelineConfig, mesh: Mesh, *,
                                         is_similarity=is_similarity,
                                         caps=caps)
 
-    S = arr if is_similarity else pearson_sharded(arr, mesh, axis=axis)
-    tm = build_tmfg_sharded(S, mesh, axis=axis, config=cfg)
-    W = apsp_mod.edge_lengths(n, tm.edges, S)
-    if cfg.apsp_method == "hub" and n >= apsp_mod.HUB_MIN_N:
-        D = apsp_hub_sharded(W, mesh, axis=axis, config=cfg)
-    else:
-        # same small-n dispatch as apsp.apsp: exact squaring, replicated
-        D = apsp_mod.apsp(W, method="exact", backend=cfg.backend)
+    def build_dense():
+        def exact(w):
+            # same small-n dispatch as apsp.apsp: exact squaring
+            return apsp_mod.apsp(w, method="exact", backend=cfg.backend)
 
-    def build_tail():
         def tail(S, tm, D):
             core = dbht_mod._dbht_device_core(
                 S, tm.edges, tm.bubble_parent, tm.bubble_tri,
@@ -630,7 +633,20 @@ def run_pipeline_sharded(X_or_S, config: PipelineConfig, mesh: Mesh, *,
                 bubble_of=core["bubble_of"], apsp=core["D"],
                 linkage=core["Z"])
 
-        return jax.jit(tail)
+        def whole(arr):
+            S = arr if is_similarity else pearson_sharded(arr, mesh,
+                                                          axis=axis)
+            tm = build_tmfg_sharded(S, mesh, axis=axis, config=cfg)
+            W = apsp_mod.edge_lengths(n, tm.edges, S)
+            if cfg.apsp_method == "hub" and n >= apsp_mod.HUB_MIN_N:
+                D = apsp_hub_sharded(W, mesh, axis=axis, config=cfg)
+            else:
+                D = _replicated(exact, mesh)(W)
+            return _replicated(tail, mesh)(S, tm, D)
 
-    fn = jitcache.cached(("sharded_dense_tail", cfg, n), build_tail)
-    return fn(S, tm, D)
+        return jax.jit(whole)
+
+    # one program for the whole dense funnel: a replay compiles nothing
+    fn = jitcache.cached(("sharded_dense", cfg, n, is_similarity, axis, mesh),
+                         build_dense)
+    return fn(arr)

@@ -27,10 +27,14 @@ matching its pre-§15 contract).
 
 from __future__ import annotations
 
+import os
 import threading
 import time
 from collections import OrderedDict
+from pathlib import Path
 from typing import Any, Callable, Dict, Hashable
+
+import jax
 
 from repro.obs import metrics as _obs_metrics
 
@@ -151,3 +155,24 @@ def _collect() -> Dict[str, float]:
 
 
 _obs_metrics.register_collector("jitcache", _collect)
+
+
+# the in-checkout home of JAX's persistent compilation cache when
+# JAX_COMPILATION_CACHE_DIR is unset: one fixed path, since the path is
+# part of what makes a cache entry findable again (.gitignore lists it)
+REPO_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_persistent_cache() -> str:
+    """Give JAX's persistent compilation cache a directory; returns it.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is overridden; otherwise the cache goes to
+    :data:`REPO_CACHE_DIR`.  Entry points (``chip_smoke.py``,
+    ``benchmarks/run.py``) call this once at start-up; importing the
+    library never does."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -46,6 +46,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.dist import sharding as dist_sh
 from repro.kernels import ops
@@ -85,6 +86,10 @@ class ClusterResult:
     # (cluster(reuse_tmfg=...)) rather than built on this similarity —
     # the stream warm-start cache keys its drift anchoring on this
     reused_tmfg: bool = False
+    # True when the fused program overflowed its slot-grid caps (§17.3)
+    # and this result comes from the staged rerun: the fused answer was
+    # not used, and a caller that checks the fused path can see it
+    overflow: bool = False
 
     def labels_at(self, k: int) -> np.ndarray:
         return self.dbht.labels(k)
@@ -236,8 +241,12 @@ def run_pipeline_device(X_or_S, config: PipelineConfig, *,
     the fused sparse/approx program (core/fused_approx.py, DESIGN.md
     §17) — same contract, no (n, n) array in the jaxpr; ``caps``
     overrides its ``(c_cap, m_cap)`` nested-HAC slot grid.  ``mesh``
-    routes the call through the multi-device funnel
-    (:func:`repro.core.distributed.run_pipeline_sharded`).
+    routes one matrix through the multi-device funnel
+    (:func:`repro.core.distributed.run_pipeline_sharded`); a batch runs
+    data-parallel over the mesh's data axes, one ``shard_map`` slice
+    per device (XLA cannot partition a Pallas kernel, so the batch is
+    split by hand, or every device runs the whole batch when B does not
+    divide the mesh).
 
     Returns :class:`DeviceOutputs` — device arrays, NO host transfer:
     callers choose what crosses the boundary (``cluster`` transfers
@@ -253,13 +262,13 @@ def run_pipeline_device(X_or_S, config: PipelineConfig, *,
             "filter='pmfg' has no fused form: greedy planarity-checked "
             "insertion is the host-orchestrated reference (DESIGN.md "
             "§18.3) — use cluster(..., fused=False)")
-    if mesh is not None:
-        from repro.core import distributed as dist_mod  # lazy: no cycle
-        return dist_mod.run_pipeline_sharded(
-            X_or_S, config, mesh, is_similarity=is_similarity, caps=caps)
     arr = jnp.asarray(X_or_S, jnp.float32)
     if batched is None:
         batched = arr.ndim == 3
+    if mesh is not None and not batched:
+        from repro.core import distributed as dist_mod  # lazy: no cycle
+        return dist_mod.run_pipeline_sharded(
+            X_or_S, config, mesh, is_similarity=is_similarity, caps=caps)
     if config.clean == "rmt" and (is_similarity or (
             is_similarity is None and arr.shape[-1] == arr.shape[-2])):
         raise ValueError(
@@ -281,17 +290,22 @@ def run_pipeline_device(X_or_S, config: PipelineConfig, *,
 
     def build():
         if config.filter != "tmfg":
-            return jax.jit(jax.vmap(_fused_filter_one(config, is_similarity))
-                           if batched
-                           else _fused_filter_one(config, is_similarity))
-        if _needs_approx_body(config):
+            one = _fused_filter_one(config, is_similarity)
+        elif _needs_approx_body(config):
             one = _fused_approx_one(config, is_similarity,
                                     int(arr.shape[-2]), caps)
         else:
             one = _fused_one(config, is_similarity)
-        return jax.jit(jax.vmap(one) if batched else one)
+        if not batched:
+            return jax.jit(one)
+        if mesh is None:
+            return jax.jit(jax.vmap(one))
+        spec = dist_sh.batch_specs(mesh, arr)
+        return jax.jit(jax.shard_map(jax.vmap(one), mesh=mesh,
+                                     in_specs=spec, out_specs=P(*spec[:1]),
+                                     check_vma=False))
 
-    key = ("fused", config, is_similarity, batched, arr.shape, caps)
+    key = ("fused", config, is_similarity, batched, arr.shape, caps, mesh)
     # the runtime recompile watchdog (DESIGN.md §15.2): a key already in
     # the executable cache is a REPLAY — if XLA compiles a new program
     # under it anyway, that is the BENCH_5 failure mode happening in
@@ -425,9 +439,11 @@ def cluster(X=None, *, S=None, moments=None, k: Optional[int] = None,
                 host.overflow))):
             # the partition exceeded the fused slot-grid caps (§17.3):
             # the staged sparse tail sizes its programs per cluster, so
-            # it is correct at any partition — rerun there
-            return cluster(X, S=S, moments=moments, k=k, config=cfg,
-                           fused=False, collect_timings=collect_timings)
+            # it is correct at any partition — rerun there, and say so
+            res = cluster(X, S=S, moments=moments, k=k, config=cfg,
+                          fused=False, collect_timings=collect_timings)
+            res.overflow = True
+            return res
         _observe_total("fused", sp.duration)
         timings = {"total": sp.duration}
         if host.counters is not None:
@@ -873,7 +889,7 @@ def cluster_batch(X=None, *, S=None, k: Optional[int] = None,
         with obs_trace.span("pipeline.fused", fence=False,
                             batch=B) as sp:
             out = run_pipeline_device(arr, cfg, is_similarity=have_S,
-                                      batched=True)
+                                      batched=True, mesh=mesh)
             # ONE transfer, sliced to B_out first so pad entries of a
             # bucketed micro-batch never cross the boundary
             host = jax.device_get(jax.tree.map(lambda a: a[:B_out], out))
@@ -881,9 +897,12 @@ def cluster_batch(X=None, *, S=None, k: Optional[int] = None,
                 host.overflow))):
             # any entry past the fused slot-grid caps (§17.3) sends the
             # whole batch to the staged path (per-cluster-sized programs)
-            return cluster_batch(X, S=S, k=k, config=cfg, mesh=mesh,
-                                 limit=limit, fused=False,
-                                 collect_timings=collect_timings)
+            out = cluster_batch(X, S=S, k=k, config=cfg, mesh=mesh,
+                                limit=limit, fused=False,
+                                collect_timings=collect_timings)
+            for r in out.results:
+                r.overflow = True
+            return out
         total = sp.duration
         _observe_total("fused", total)
         if host.counters is not None:

@@ -31,7 +31,6 @@ the production path.
 
 from __future__ import annotations
 
-import inspect
 from typing import Any, Optional, Tuple
 
 import numpy as np
@@ -49,29 +48,6 @@ _MIN_SHARD_ELEMS = 1 << 16
 # ---------------------------------------------------------------------------
 # mesh helpers
 # ---------------------------------------------------------------------------
-
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes it at top level; 0.4.x has
-    ``jax.experimental.shard_map.shard_map``.  The replication-check
-    kwarg was renamed ``check_rep`` -> ``check_vma`` along the way (top-
-    level availability and the rename happened in *different* releases),
-    so the kwarg name is probed from the resolved function's signature.
-    Every shard_map in this codebase goes through here so the
-    per-version dance lives in exactly one place.
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-
-    kwargs = {}
-    if check_vma is not None:
-        params = inspect.signature(fn).parameters
-        key = "check_vma" if "check_vma" in params else "check_rep"
-        kwargs[key] = check_vma
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **kwargs)
 
 def data_axes(mesh: Mesh) -> Tuple[str, ...]:
     """The pure-data-parallel axes present in ``mesh`` (pod before data)."""
@@ -145,35 +121,33 @@ def pearson_shardmap(X: jax.Array, mesh: Mesh, axis="data") -> jax.Array:
     def f(xl):
         z = kref.standardize_rows(xl.astype(jnp.float32))
         zf = lax.all_gather(z, axis, tiled=True)          # (n, L)
-        return jnp.clip(zf @ z.T, -1.0, 1.0)              # (n, n/d)
+        return jnp.clip(jnp.dot(zf, z.T, precision=kref.SIM_PRECISION),
+                        -1.0, 1.0)                        # (n, n/d)
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh, in_specs=timeseries_spec(axis),
         out_specs=similarity_spec(axis))(X)
 
 
 def topk_pearson_sharded(X: jax.Array, k: int, mesh: Mesh, axis="data",
-                         *, bm: int = 512):
+                         *, bm: int = 128, backend: str = "auto"):
     """Blocked top-K Pearson with X row-sharded (DESIGN.md §17.4).
 
     Each device owns a row panel: standardize local rows, all-gather
-    the standardized series (the one collective), then scan ``bm``-row
-    sub-panels of the local block — per sub-panel one (bm, n) full-width
-    matmul and ONE ``lax.top_k``.  This is exactly the single-device
-    ``kernels.topk.topk_pearson_jnp`` scan restricted to the local
-    rows, so the table is bitwise the single-device table (value desc,
-    index asc tie order) with no running merge at all.  An earlier
-    column-tiled formulation kept a per-tile O(K) merge; the per-tile
-    ``top_k`` + merge cost ~8x more than the full-width scan on CPU,
-    so the row-panel form is both the parity argument and the fast one.
+    the standardized series (the one collective), then run the
+    single-device scan's own kernel (``kernels.ops.topk_rows``: the
+    Pallas streaming kernel on a TPU, the jnp row-panel scan elsewhere)
+    on the local rows against all n columns.  Each row's top-K depends
+    on that row alone, so the table is bitwise the single-device table
+    (value desc, index asc tie order) on every backend.
 
     Returns ``(values (n, k), indices (n, k), Z (n, L))`` — Z is the
     standardized series the sparse TMFG's exact-value fallback reads.
     Rows are padded to the axis size internally; pad rows never appear
     as candidates.
     """
-    from repro.kernels import ref as kref       # local import: no cycle
-    from repro.kernels.topk import NEG
+    from repro.kernels import ops                # local import: no cycle
+    from repro.kernels import ref as kref
 
     X = jnp.asarray(X, jnp.float32)
     n, L = X.shape
@@ -182,35 +156,18 @@ def topk_pearson_sharded(X: jax.Array, k: int, mesh: Mesh, axis="data",
     pad = (-n) % d
     if pad:
         X = jnp.concatenate([X, jnp.zeros((pad, L), jnp.float32)])
-    n_pad = n + pad
-    n_loc = n_pad // d
-    bm_t = max(min(bm, n_loc), 1)
-    n_panels = -(-n_loc // bm_t)
-    row_pad = n_panels * bm_t - n_loc
+    n_loc = (n + pad) // d
 
     def f(xl):
         z = kref.standardize_rows(xl)                       # (n_loc, L)
         zf = lax.all_gather(z, axis, tiled=True)            # (n_pad, L)
-        gid0 = lax.axis_index(axis) * n_loc
-        zp_all = jnp.concatenate(
-            [z, jnp.zeros((row_pad, L), jnp.float32)]) if row_pad else z
-        cols = jnp.arange(n_pad)
+        rid = lax.axis_index(axis) * n_loc + jnp.arange(n_loc,
+                                                        dtype=jnp.int32)
+        v, i = ops.topk_rows(z, zf[:n], rid, k, backend=backend,
+                             bm=min(bm, n_loc))
+        return v, i, zf
 
-        def panel(_, p0):
-            zp = lax.dynamic_slice(zp_all, (p0, 0), (bm_t, L))
-            s = jnp.clip(zp @ zf.T, -1.0, 1.0)              # (bm_t, n_pad)
-            rid = gid0 + p0 + jnp.arange(bm_t)
-            bad = (cols[None, :] == rid[:, None]) | (cols[None, :] >= n)
-            s = jnp.where(bad, NEG, s)
-            cv, ci = lax.top_k(s, k)
-            return None, (cv, ci.astype(jnp.int32))
-
-        starts = jnp.arange(n_panels, dtype=jnp.int32) * bm_t
-        _, (v, i) = lax.scan(panel, None, starts)
-        return (v.reshape(n_panels * bm_t, k)[:n_loc],
-                i.reshape(n_panels * bm_t, k)[:n_loc], zf)
-
-    v, i, z = shard_map(
+    v, i, z = jax.shard_map(
         f, mesh=mesh, in_specs=timeseries_spec(axis),
         out_specs=(P(axis, None), P(axis, None), P()),
         check_vma=False)(X)
@@ -227,7 +184,7 @@ def masked_argmax_shardmap(S: jax.Array, mask: jax.Array, mesh: Mesh,
     def f(sl):
         return ops.masked_argmax(sl, mask, backend=backend)
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh, in_specs=P(axis, None), out_specs=(P(axis), P(axis)),
         check_vma=False)(S)
 
@@ -245,7 +202,7 @@ def minplus_shardmap(A: jax.Array, B: jax.Array, mesh: Mesh, axis="data", *,
     def f(al, b):
         return ops.minplus(al, b, backend=backend)
 
-    return shard_map(
+    return jax.shard_map(
         f, mesh=mesh, in_specs=(P(axis, None), P(None, None)),
         out_specs=P(axis, None), check_vma=False)(A, B)
 

@@ -7,8 +7,9 @@ Backends:
   * ``"jnp"``       — pure-jnp oracle/fallback (fast on CPU via XLA).
   * ``"auto"``      — pallas on TPU, jnp elsewhere.
 
-The default is "auto" so the same library code runs correctly here (CPU)
-and fast on the target hardware.
+The default is "auto" so the same library code runs on a CPU (the tests,
+with jnp kernels or interpret mode) and on the TPU (compiled kernels).
+``sparse_relax`` has one XLA form on every platform (no Mosaic form).
 """
 
 from __future__ import annotations
@@ -21,7 +22,8 @@ from . import sparse_apsp as _sparse
 from .gainscan import masked_argmax_pallas
 from .minplus import minplus_jnp, minplus_pallas
 from .pearson import pearson_pallas
-from .topk import topk_pearson_jnp, topk_pearson_pallas
+from .topk import (topk_pearson_jnp, topk_pearson_pallas, topk_rows_jnp,
+                   topk_rows_pallas)
 
 
 def _resolve(backend: str) -> str:
@@ -31,7 +33,7 @@ def _resolve(backend: str) -> str:
 
 
 def minplus(A: jax.Array, B: jax.Array, *, backend: str = "auto",
-            bm: int = 128, bk: int = 16, bn: int = 128) -> jax.Array:
+            bm: int = 128, bk: int = 128, bn: int = 128) -> jax.Array:
     """Tropical matmul: out[i,j] = min_k A[i,k] + B[k,j]."""
     b = _resolve(backend)
     if b == "pallas":
@@ -63,15 +65,14 @@ def masked_argmax(S: jax.Array, mask: jax.Array, *, backend: str = "auto",
     return ref.masked_argmax_ref(S, mask)
 
 
-def sparse_relax(D: jax.Array, graph, *, backend: str = "auto",
-                 be: int = 8192) -> jax.Array:
+def sparse_relax(D: jax.Array, graph, *,
+                 backend: str = "auto") -> jax.Array:
     """One multi-source tropical SpMM round against a CSR adjacency.
 
-    out[s, v] = min(D[s, v], min over CSR entries (u, v) of D[s, u] + w).
-    Every backend converges to the same fixed point bitwise — ``min`` is
-    exact in floats (DESIGN.md §14.1).  ``graph`` is a
-    ``kernels.sparse_apsp.CSRGraph``."""
-    return _sparse.sparse_relax(D, graph, backend=backend, be=be)
+    out[s, v] = min(D[s, v], min over CSR entries (u, v) of D[s, u] + w)
+    (DESIGN.md §14.1).  ``graph`` is a ``kernels.sparse_apsp.CSRGraph``.
+    One XLA form on every platform: ``backend`` selects nothing here."""
+    return _sparse.sparse_relax(D, graph, backend=backend)
 
 
 def topk(X: jax.Array, k: int, *, backend: str = "auto", bm: int = 128,
@@ -87,3 +88,16 @@ def topk(X: jax.Array, k: int, *, backend: str = "auto", bm: int = 128,
     if b == "interpret":
         return topk_pearson_pallas(X, k, bm=bm, bn=bn, interpret=True)
     return topk_pearson_jnp(X, k, bm=bm)
+
+
+def topk_rows(Zr: jax.Array, Z: jax.Array, rid: jax.Array, k: int, *,
+              backend: str = "auto", bm: int = 128, bn: int = 128):
+    """Top-k partners among the standardized rows Z (n, L) for the
+    standardized rows Zr (m, L) with global ids rid (m,) — one row panel
+    of ``topk``; the sharded scan's per-device step."""
+    b = _resolve(backend)
+    if b == "pallas":
+        return topk_rows_pallas(Zr, Z, rid, k, bm=bm, bn=bn)
+    if b == "interpret":
+        return topk_rows_pallas(Zr, Z, rid, k, bm=bm, bn=bn, interpret=True)
+    return topk_rows_jnp(Zr, Z, rid, k, bm=bm)

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .ref import SIM_PRECISION
+
 
 def _pearson_kernel(x_ref, y_ref, mx_ref, rx_ref, my_ref, ry_ref, o_ref):
     """Grid (i, j, l): o[i,j] += std(x[i,l]) @ std(y[j,l]).T"""
@@ -28,7 +30,8 @@ def _pearson_kernel(x_ref, y_ref, mx_ref, rx_ref, my_ref, ry_ref, o_ref):
 
     x = (x_ref[...] - mx_ref[...]) * rx_ref[...]      # (bm, bl) standardized
     y = (y_ref[...] - my_ref[...]) * ry_ref[...]      # (bn, bl)
-    o_ref[...] += jnp.dot(x, y.T, preferred_element_type=jnp.float32)
+    o_ref[...] += jnp.dot(x, y.T, preferred_element_type=jnp.float32,
+                          precision=SIM_PRECISION)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bl", "interpret"))

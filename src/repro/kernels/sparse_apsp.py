@@ -1,4 +1,4 @@
-"""Sparse APSP on the TMFG edge list: blocked multi-source relaxation.
+"""Sparse APSP on the TMFG edge list: multi-source relaxation.
 
 The TMFG is planar — exactly 3n-6 edges — so the APSP stage never needs
 the dense (n, n) length matrix the min-plus kernels square (DESIGN.md
@@ -14,18 +14,15 @@ gather of the tail distances along the edge list, an elementwise add of
 the edge lengths, and a segmented min back into the head vertices —
 O(s·E) work and O(s·n + E) memory, never (n, n).
 
-Backends (the ``kernels/ops.py`` dispatch convention):
-  * ``"jnp"``       — one gather + ``jax.ops.segment_min`` per round (the
-    CSR entries are row-sorted, so the segmented min is a linear sweep).
-  * ``"pallas"`` / ``"interpret"`` — the gather+add half (the bandwidth-
-    bound part) runs as a blocked Pallas kernel over (source, edge)
-    tiles with the distance row panel resident in VMEM; the segmented
-    min composes in XLA as a deterministic ``.at[...].min`` scatter.
+One form on every platform: a gather + ``jax.ops.segment_min`` per
+round (the CSR entries are row-sorted, so the segmented min is a linear
+sweep).  The ``backend`` argument is accepted for the ``kernels/ops.py``
+calling convention and changes nothing: Mosaic has no lane-axis gather
+at these widths, so there is no Pallas form to select.
 
-Every backend computes the same fixed point bitwise: ``min`` is exact in
-floats (no rounding), so the relaxation order — blocked, segmented, or
-scattered — cannot change a single bit of the converged distances
-(pinned by tests/test_sparse_apsp.py against a numpy reference).
+The fixed point is exact: ``min`` does not round, so the relaxation order
+cannot change a single bit of the converged distances (pinned by
+tests/test_sparse_apsp.py against a numpy reference).
 """
 
 from __future__ import annotations
@@ -36,7 +33,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import pallas as pl
 
 INF = jnp.inf
 
@@ -97,96 +93,30 @@ def hub_strength(graph: CSRGraph) -> jax.Array:
 # one relaxation round, per backend
 # ---------------------------------------------------------------------------
 
-def _gather_add_kernel(d_ref, col_ref, val_ref, o_ref):
-    """Pallas tile: o[s, e] = d[s, cols[e]] + vals[e].
-
-    The (bs, n) distance row panel stays resident in VMEM across the
-    edge-block grid axis; the dynamic gather along the lane axis is the
-    kernel's whole point (see /opt/skills/guides — refs support dynamic
-    index vectors; on CPU the interpret path executes the same body).
-    """
-    d = d_ref[...]                                   # (bs, n)
-    cols = col_ref[...]                              # (be,)
-    o_ref[...] = jnp.take(d, cols, axis=1) + val_ref[...][None, :]
-
-
-@functools.partial(jax.jit, static_argnames=("bs", "be", "interpret"))
-def gather_add_pallas(D: jax.Array, cols: jax.Array, vals: jax.Array, *,
-                      bs: int = 32, be: int = 4096,
-                      interpret: bool = False) -> jax.Array:
-    """(s, n) distances + (m,) edge tails/weights -> (s, m) candidates."""
-    s, n = D.shape
-    m = cols.shape[0]
-    bs_, be_ = min(bs, s), min(be, m)
-    ps, pe = (-s) % bs_, (-m) % be_
-    Dp = jnp.pad(D.astype(jnp.float32), ((0, ps), (0, 0)))
-    colp = jnp.pad(cols, (0, pe))                    # pad gathers col 0
-    valp = jnp.pad(vals.astype(jnp.float32), (0, pe),
-                   constant_values=INF)              # inf: never wins a min
-    out = pl.pallas_call(
-        _gather_add_kernel,
-        grid=(Dp.shape[0] // bs_, colp.shape[0] // be_),
-        in_specs=[
-            pl.BlockSpec((bs_, n), lambda i, j: (i, 0)),
-            pl.BlockSpec((be_,), lambda i, j: (j,)),
-            pl.BlockSpec((be_,), lambda i, j: (j,)),
-        ],
-        out_specs=pl.BlockSpec((bs_, be_), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Dp.shape[0], colp.shape[0]),
-                                       jnp.float32),
-        interpret=interpret,
-    )(Dp, colp, valp)
-    return out[:s, :m]
-
-
-def sparse_relax(D: jax.Array, graph: CSRGraph, *, backend: str = "auto",
-                 be: int = 8192) -> jax.Array:
+def sparse_relax(D: jax.Array, graph: CSRGraph, *,
+                 backend: str = "auto") -> jax.Array:
     """One multi-source relaxation round: tropical SpMM against the CSR.
 
     Returns ``min(D, candidates)`` — monotone non-increasing, so iterating
     to a fixed point yields the (unique) single-source distances from
-    every row's source set.  Dispatch follows ``kernels/ops.py``.
+    every row's source set.  ``backend`` is accepted and ignored: this
+    round has one XLA form on every platform (module docstring).
     """
-    from . import ops  # local: ops imports this module's jit wrappers
-
-    b = ops._resolve(backend)
-    n = graph.n
-    if b == "jnp":
-        cand = D[:, graph.cols] + graph.vals[None, :]          # (s, m)
-        upd = jax.ops.segment_min(cand.T, graph.rows, num_segments=n,
-                                  indices_are_sorted=True)     # (n, s)
-        return jnp.minimum(D, upd.T)
-
-    # pallas / interpret: blocked gather+add kernel + deterministic
-    # scatter-min per edge block (min is exact — blocking cannot change
-    # the fixed point, see module docstring)
-    m = graph.rows.shape[0]
-    be_ = min(be, m)
-    pe = (-m) % be_
-    rowp = jnp.pad(graph.rows, (0, pe))
-    colp = jnp.pad(graph.cols, (0, pe))
-    valp = jnp.pad(graph.vals, (0, pe), constant_values=INF)
-    nblk = rowp.shape[0] // be_
-    blocks = (rowp.reshape(nblk, be_), colp.reshape(nblk, be_),
-              valp.reshape(nblk, be_))
-
-    def body(Dcur, blk):
-        r, c, v = blk
-        cand = gather_add_pallas(Dcur, c, v, interpret=(b == "interpret"))
-        return Dcur.at[:, r].min(cand), None
-
-    out, _ = lax.scan(body, D, blocks)
-    return out
+    del backend
+    cand = D[:, graph.cols] + graph.vals[None, :]              # (s, m)
+    upd = jax.ops.segment_min(cand.T, graph.rows, num_segments=graph.n,
+                              indices_are_sorted=True)         # (n, s)
+    return jnp.minimum(D, upd.T)
 
 
 # ---------------------------------------------------------------------------
 # multi-source fixed point
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("rounds", "backend", "be"))
+@functools.partial(jax.jit, static_argnames=("rounds", "backend"))
 def sparse_apsp_sources(graph: CSRGraph, sources: jax.Array, *,
-                        rounds: int = 0, backend: str = "auto",
-                        be: int = 8192) -> jax.Array:
+                        rounds: int = 0,
+                        backend: str = "auto") -> jax.Array:
     """Distances (s, n) from ``sources`` by iterated sparse relaxation.
 
     Frontier-style early exit: the while_loop stops as soon as a round
@@ -210,7 +140,7 @@ def sparse_apsp_sources(graph: CSRGraph, sources: jax.Array, *,
 
     def body(carry):
         i, D, _ = carry
-        D2 = sparse_relax(D, graph, backend=backend, be=be)
+        D2 = sparse_relax(D, graph, backend=backend)
         return i + 1, D2, jnp.any(D2 < D)
 
     _, D, _ = lax.while_loop(cond, body, (0, D0, jnp.bool_(True)))

@@ -323,7 +323,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
 
         mem = compiled.memory_analysis()
         print(f"[{tag}] memory_analysis: {mem}")
-        ca = hlo_cost.xla_cost_dict(compiled)
+        ca = compiled.cost_analysis()
         print(f"[{tag}] cost_analysis flops={ca.get('flops')} "
               f"bytes={ca.get('bytes accessed')}")
         hlo_text = compiled.as_text()

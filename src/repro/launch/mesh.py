@@ -7,25 +7,14 @@ initialization).
 
 from __future__ import annotations
 
-import inspect
-
 import jax
 
 
 def _make(shape, axes, devices=None):
-    """jax.make_mesh across jax versions: ``axis_types`` (explicit-Auto)
-    only exists on newer jax; older releases are Auto-only anyway.  The
-    kwarg is probed from make_mesh's own signature (AxisType existing in
-    jax.sharding does not guarantee make_mesh accepts it — availability
-    and kwarg support landed in different releases)."""
-    kwargs = {}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if (axis_type is not None
-            and "axis_types" in inspect.signature(jax.make_mesh).parameters):
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axes)
-    if devices is not None:
-        kwargs["devices"] = devices
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+    """``jax.make_mesh`` with every axis explicitly ``AxisType.Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -44,6 +44,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ref import SIM_PRECISION
+
 
 class WindowState(NamedTuple):
     """Rolling-window ring buffer + compensated co-moment sums.
@@ -103,7 +105,7 @@ def _full_moments(buf: jax.Array, ref: jax.Array, dt):
     restart at zero."""
     Z = (buf - ref[:, None]).astype(dt)
     s1 = jnp.sum(Z, axis=1)
-    s2 = Z @ Z.T
+    s2 = jnp.dot(Z, Z.T, precision=SIM_PRECISION)
     return ref, s1, jnp.zeros_like(s1), s2, jnp.zeros_like(s2)
 
 

@@ -88,6 +88,16 @@ SCRIPT_BATCH = textwrap.dedent("""
     for b in range(4):
         single = cluster(Xb[b], k=3, variant="opt")
         assert (single.labels == bres.labels[b]).all(), b
+
+    # dense sharded funnel below HUB_MIN_N (exact APSP, replicated):
+    # same labels as one device, and a replay compiles nothing
+    from repro.obs.trace import watch_recompiles
+    sharded = cluster(Xb[0], k=3, variant="opt", mesh=mesh)
+    assert (sharded.labels == bres.labels[0]).all()
+    with watch_recompiles() as w:
+        again = cluster(Xb[0], k=3, variant="opt", mesh=mesh)
+    assert w.count == 0, w.count
+    assert (again.labels == sharded.labels).all()
     print("BATCH-OK")
 """)
 

@@ -17,14 +17,13 @@ Pins of ISSUE 4's acceptance criteria:
 import numpy as np
 import pytest
 
-import jax._src.test_util as jtu
-
 from conftest import clustered_similarity
 from repro.core import jitcache
 from repro.core.config import PipelineConfig
 from repro.core.pipeline import (VARIANTS, cluster, cluster_batch,
                                  run_pipeline_device)
 from repro.data.timeseries import make_dataset
+from repro.obs import trace as obs_trace
 
 
 def _assert_linkage_equal(a, b, msg=""):
@@ -133,7 +132,7 @@ def test_identical_config_and_shape_compiles_once():
     """ISSUE 4 satellite: replaying one (PipelineConfig, shape) through
     cluster() and cluster_batch() lowers each device program exactly
     once — later calls hit the cached executables, producing ZERO new
-    lowerings (counted at jax's mlir lowering hook)."""
+    XLA compilations (counted by the repo's recompile watchdog)."""
     cfg = PipelineConfig.opt()
     X, _ = make_dataset(32, 24, 2, noise=0.7, seed=3)
     Xb = np.stack([make_dataset(32, 24, 2, noise=0.7, seed=s)[0]
@@ -145,11 +144,11 @@ def test_identical_config_and_shape_compiles_once():
     grew = jitcache.size()
     assert grew >= 2                            # single + batched executables
 
-    with jtu.count_jit_and_pmap_lowerings() as count:
+    with obs_trace.watch_recompiles() as w:
         for _ in range(3):
             r1 = cluster(X, k=2, config=cfg)
             rb = cluster_batch(Xb, k=2, config=cfg)
-    assert count[0] == 0, f"recompiled {count[0]} programs on replay"
+    assert w.count == 0, f"recompiled {w.count} programs on replay"
     assert jitcache.size() == grew              # no new executables either
     np.testing.assert_array_equal(rb.labels[0], cluster(Xb[0], config=cfg,
                                                         k=2).labels)

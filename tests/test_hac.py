@@ -43,6 +43,17 @@ def test_linkage_backend_parity(backend):
     assert (Z == Z_ref).all()
 
 
+@pytest.mark.parametrize("backend", ["jnp", "interpret"])
+def test_linkage_ties_match_oracle(backend):
+    """Integer distances tie everywhere: the cached nearest neighbours
+    must keep the oracle's lowest-flat-index merge order."""
+    A = np.random.default_rng(3).integers(0, 4, (40, 40)).astype(float)
+    D = np.minimum(A, A.T)
+    Z_ref = R.complete_linkage(D.copy())
+    Z = np.asarray(hac.complete_linkage(jnp.asarray(D), backend=backend))
+    np.testing.assert_array_equal(Z, Z_ref)
+
+
 def test_linkage_heights_monotone():
     D = _rand_dist(50, 7)
     Z = np.asarray(hac.complete_linkage(jnp.asarray(D)))

@@ -39,7 +39,7 @@ def test_scan_multiplies_trip_count():
     x = jax.ShapeDtypeStruct((128, 256), jnp.float32)
     totals, compiled = _cost(f, ws, x)
     want = 8 * 2 * 128 * 256 * 256
-    xla = hlo_cost.xla_cost_dict(compiled)["flops"]
+    xla = compiled.cost_analysis()["flops"]
     assert xla < want / 4, "XLA undercounts (that's the premise)"
     assert abs(totals.flops - want) / want < 0.10, \
         f"got {totals.flops}, want ~{want}"

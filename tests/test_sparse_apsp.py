@@ -79,8 +79,9 @@ def test_sparse_sources_match_numpy_bellman_ford(n):
 
 
 def test_sparse_backends_agree_bitwise():
-    """jnp / interpret / auto produce identical bits (§14.1: exact-min
-    combining makes the edge-block order irrelevant)."""
+    """jnp / interpret / auto produce identical bits: the round has one
+    XLA form and takes ``backend`` only for the ops calling convention
+    (§14.1)."""
     _, _, W = _tmfg_lengths(40, seed=7)
     graph = A.csr_from_dense(W)
     src = jnp.arange(8, dtype=jnp.int32)
