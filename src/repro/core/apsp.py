@@ -83,37 +83,28 @@ def edge_lengths(n: int, edges: jax.Array, S: jax.Array) -> jax.Array:
     return W
 
 
+def _exact_steps(n: int) -> int:
+    """Min-plus squarings :func:`apsp_exact` runs: ceil(log2(n-1))."""
+    return max(1, math.ceil(math.log2(max(n - 1, 2))))
+
+
 @functools.partial(jax.jit, static_argnames=("backend",))
 def apsp_exact(W: jax.Array, *, backend: str = "auto") -> jax.Array:
     """Exact APSP by repeated min-plus squaring (assumes W symmetric, 0 diag)."""
-    n = W.shape[0]
-    steps = max(1, math.ceil(math.log2(max(n - 1, 2))))
     D = W
 
     def body(D, _):
         return ops.minplus(D, D, backend=backend), None
 
-    D, _ = jax.lax.scan(body, D, None, length=steps)
+    D, _ = jax.lax.scan(body, D, None, length=_exact_steps(W.shape[0]))
     return D
 
 
-@functools.partial(jax.jit, static_argnames=("n_hubs", "rounds", "backend"))
-def apsp_hub(W: jax.Array, *, n_hubs: int = 0, rounds: int = 0,
-             backend: str = "auto") -> jax.Array:
-    """Hub-based approximate APSP (paper optimization C3, TPU formulation).
-
-    Args:
-      W: dense (n, n) length matrix (inf off-graph, 0 diagonal).
-      n_hubs: number of hub vertices; 0 means ceil(sqrt(n)).
-      rounds: Bellman-Ford relaxation cap for the hub rows; 0 (the
-        default) relaxes to the fixed point with the true n-round bound
-        as the cap.  The loop exits as soon as a round changes nothing,
-        so the generous cap costs nothing once converged — a fixed
-        truncation (the old ``rounds=32`` default) silently left
-        unreachable-looking ``inf`` distances whenever the TMFG's
-        hop-diameter exceeded it, which real graphs hit from n ≈ 1000
-        (the BENCH_9 sparse-tail shattering).
-    """
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _apsp_hub_rounds(W: jax.Array, n_hubs: int, rounds: int, backend: str):
+    """:func:`apsp_hub`'s program, plus the Bellman-Ford rounds it ran
+    (the while_loop's counter, the last round being the one that changed
+    nothing unless the cap stopped it)."""
     n = W.shape[0]
     h = hub_count(n, n_hubs)
     cap = rounds if rounds else n
@@ -137,14 +128,35 @@ def apsp_hub(W: jax.Array, *, n_hubs: int = 0, rounds: int = 0,
         D2 = jnp.minimum(D_h, ops.minplus(D_h, W, backend=backend))
         return i + 1, D2, jnp.any(D2 < D_h)
 
-    _, D_h, _ = jax.lax.while_loop(cond, body, (0, D_h0, jnp.bool_(True)))
+    i, D_h, _ = jax.lax.while_loop(cond, body,
+                                   (0, D_h0, jnp.bool_(True)))
 
     # composition through hubs + exact 1-hop floor
     est = ops.minplus(D_h.T, D_h, backend=backend)      # (n, n)
     est = jnp.minimum(est, W)
     est = jnp.minimum(est, est.T)
     est = est.at[jnp.arange(n), jnp.arange(n)].set(0.0)
-    return est
+    return est, i
+
+
+@functools.partial(jax.jit, static_argnames=("n_hubs", "rounds", "backend"))
+def apsp_hub(W: jax.Array, *, n_hubs: int = 0, rounds: int = 0,
+             backend: str = "auto") -> jax.Array:
+    """Hub-based approximate APSP (paper optimization C3, TPU formulation).
+
+    Args:
+      W: dense (n, n) length matrix (inf off-graph, 0 diagonal).
+      n_hubs: number of hub vertices; 0 means ceil(sqrt(n)).
+      rounds: Bellman-Ford relaxation cap for the hub rows; 0 (the
+        default) relaxes to the fixed point with the true n-round bound
+        as the cap.  The loop exits as soon as a round changes nothing,
+        so the generous cap costs nothing once converged — a fixed
+        truncation (the old ``rounds=32`` default) silently left
+        unreachable-looking ``inf`` distances whenever the TMFG's
+        hop-diameter exceeded it, which real graphs hit from n ≈ 1000
+        (the BENCH_9 sparse-tail shattering).
+    """
+    return _apsp_hub_rounds(W, n_hubs, rounds, backend)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("n_hubs", "rounds", "backend"))
@@ -165,12 +177,18 @@ def hub_factor_sparse(graph, *, n_hubs: int = 0, rounds: int = 0,
     which the sparse DBHT tail evaluates in (panel, n) blocks
     (core/sparse_dbht.py) — the full (n, n) matrix never exists.
     """
+    del backend           # sparse relaxation has one XLA form everywhere
+    return hub_factor_sparse_rounds(graph, n_hubs, rounds)[:2]
+
+
+def hub_factor_sparse_rounds(graph, n_hubs: int, rounds: int):
+    """:func:`hub_factor_sparse` plus the relaxation rounds it ran:
+    ``(hubs, D_h, rounds_run)``; traceable inside a caller's jit."""
     h = hub_count(graph.n, n_hubs)
     strength = sparse_kernels.hub_strength(graph)
     hubs = jax.lax.top_k(strength, h)[1]
-    D_h = sparse_kernels.sparse_apsp_sources(graph, hubs, rounds=rounds,
-                                             backend=backend)
-    return hubs, D_h
+    D_h, i = sparse_kernels.relax_to_fixed_point(graph, hubs, rounds)
+    return hubs, D_h, i
 
 
 def csr_from_dense(W) -> "sparse_kernels.CSRGraph":
@@ -225,12 +243,22 @@ def apsp(W: jax.Array, *, method: str = "hub", n_hubs: int = 0,
     accurate answer, faster.  Call :func:`apsp_hub` directly to force
     the hub program shape regardless of n.
     """
-    if method == "exact":
-        return apsp_exact(W, backend=backend)
-    if method == "hub":
-        if W.shape[0] < HUB_MIN_N:
-            return apsp_exact(W, backend=backend)
-        return apsp_hub(W, n_hubs=n_hubs, rounds=rounds, backend=backend)
     if method == "sparse":
         return apsp_sparse(W, n_hubs=n_hubs, rounds=rounds, backend=backend)
+    return apsp_rounds(W, method=method, n_hubs=n_hubs, rounds=rounds,
+                       backend=backend)[0]
+
+
+def apsp_rounds(W: jax.Array, *, method: str = "hub", n_hubs: int = 0,
+                rounds: int = 0, backend: str = "auto"):
+    """:func:`apsp` for the dense methods, plus the rounds it ran:
+    ``(D, rounds_run)`` — the Bellman-Ford rounds of the hub program, or
+    the fixed number of squarings of the exact one.  Traceable: the
+    fused pipeline returns the count as a loop counter (DESIGN.md
+    §15.5)."""
+    if method == "exact" or (method == "hub" and W.shape[0] < HUB_MIN_N):
+        return (apsp_exact(W, backend=backend),
+                jnp.int32(_exact_steps(W.shape[0])))
+    if method == "hub":
+        return _apsp_hub_rounds(W, n_hubs, rounds, backend)
     raise ValueError(f"unknown APSP method {method!r}")

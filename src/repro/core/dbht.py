@@ -325,22 +325,30 @@ def _device_assign(D: jax.Array, bubble_verts: jax.Array,
 
 
 def _dbht_device_core(S, edges, bubble_parent, bubble_tri, bubble_verts,
-                      home_bubble, D, *, backend: str = "auto"):
+                      home_bubble, D, *, backend: str = "auto", mark=None):
     """Traceable single-matrix device DBHT: TMFG arrays + APSP → outputs.
 
     Everything is fixed-shape, so the whole stage jit-compiles and vmaps
     over a batch axis (DESIGN.md §11).  ``conv_mask`` stands in for the
     variable-length converging-id list until the (single) host transfer.
+    ``hac_rescans`` is the nested HAC's rescan count (DESIGN.md §15.5);
+    ``mark``, an ``obs.trace.StageMarks``, marks the DBHT/HAC boundary.
     """
-    anc = _anc_matrix(bubble_parent)
-    direction = _device_directions(S, edges, bubble_tri, home_bubble, anc)
-    _, dest, conv_mask = _device_flow(bubble_parent, direction)
-    cluster_of, bubble_of, _ = _device_assign(
-        D, bubble_verts, home_bubble, dest, conv_mask)
-    adj = hac_mod.hierarchical_offsets(D, bubble_of, cluster_of)
-    Z = hac_mod.complete_linkage(adj, backend=backend)
+    with jax.named_scope("dbht"):
+        anc = _anc_matrix(bubble_parent)
+        direction = _device_directions(S, edges, bubble_tri, home_bubble,
+                                       anc)
+        _, dest, conv_mask = _device_flow(bubble_parent, direction)
+        cluster_of, bubble_of, _ = _device_assign(
+            D, bubble_verts, home_bubble, dest, conv_mask)
+        adj = hac_mod.hierarchical_offsets(D, bubble_of, cluster_of)
+    if mark is not None:
+        adj = mark("dbht", adj)
+    with jax.named_scope("hac"):
+        Z, rescans = hac_mod.complete_linkage_rescans(adj, backend=backend)
     return dict(direction=direction, conv_mask=conv_mask,
-                cluster_of=cluster_of, bubble_of=bubble_of, D=D, Z=Z)
+                cluster_of=cluster_of, bubble_of=bubble_of, D=D, Z=Z,
+                hac_rescans=rescans)
 
 
 def _device_dbht_jit(apsp_method: str, apsp_hubs: int, apsp_rounds: int,

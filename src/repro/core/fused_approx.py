@@ -262,7 +262,8 @@ def _slot_hac(D_h, graph: CSRGraph, bubble_of, counts, bounds, perm,
     so the local merge rows are bitwise staged.  Rows are normalized to
     slot-grid ids — leaf = member position (< m_cap), internal =
     m_cap + local row — and padded to (m_cap-1, 4) with +inf heights.
-    Returns (rows (c_cap, m_cap-1, 4), members (c_cap, m_cap))."""
+    Returns (rows (c_cap, m_cap-1, 4), members (c_cap, m_cap), the HAC
+    rescans summed over the slots)."""
     h, n = D_h.shape
     tiers = []
     t = 2
@@ -293,7 +294,8 @@ def _slot_hac(D_h, graph: CSRGraph, bubble_of, counts, bounds, perm,
             adj = Dc + jnp.where(cross, m1, 0.0)
             vt = valid[:m_pad]
             adj = jnp.where(vt[:, None] & vt[None, :], adj, INF)
-            Z = hac_mod.complete_linkage(adj, backend=backend)
+            Z, rescans = hac_mod.complete_linkage_rescans(adj,
+                                                          backend=backend)
             l_, r_ = Z[:, 0], Z[:, 1]                        # tier-local ids
             l_ = jnp.where(l_ < m_pad, l_, l_ + (m_cap - m_pad))
             r_ = jnp.where(r_ < m_pad, r_, r_ + (m_cap - m_pad))
@@ -302,7 +304,7 @@ def _slot_hac(D_h, graph: CSRGraph, bubble_of, counts, bounds, perm,
             if pad:
                 Zn = jnp.concatenate(
                     [Zn, jnp.full((pad, 4), INF, jnp.float32)], axis=0)
-            return Zn
+            return Zn, rescans
 
         return br
 
@@ -323,13 +325,13 @@ def _slot_hac(D_h, graph: CSRGraph, bubble_of, counts, bounds, perm,
         bloc = jnp.where(valid, bubble_of[idx], -1)
         tier_ix = jnp.minimum(jnp.sum((tarr < m_c).astype(jnp.int32)),
                               len(tiers) - 1)                # next_pow2(m)
-        Zs = lax.switch(tier_ix, branches,
-                        (idx, valid, bloc, li, lj, e_ok, m_c))
-        return None, (Zs, idx)
+        Zs, rescans = lax.switch(tier_ix, branches,
+                                 (idx, valid, bloc, li, lj, e_ok, m_c))
+        return None, (Zs, idx, rescans)
 
-    _, (all_rows, members) = lax.scan(slot_body, None,
-                                      jnp.arange(c_cap, dtype=jnp.int32))
-    return all_rows, members
+    _, (all_rows, members, rescans) = lax.scan(
+        slot_body, None, jnp.arange(c_cap, dtype=jnp.int32))
+    return all_rows, members, jnp.sum(rescans)
 
 
 def _assemble_device(n: int, all_rows, members, counts_perm, perm, Zt,
@@ -417,7 +419,7 @@ def _sparse_tail(cfg, n: int, tm: TMFGResult, w_sim, c_cap: int,
 
     The traceable form of ``sparse_dbht.dbht_sparse``'s device stages;
     returns a dict matching ``dbht._dbht_device_core``'s plus
-    (hubs, overflow)."""
+    (hubs, overflow, apsp_rounds)."""
     from repro.core import dbht as dbht_mod  # local: no import cycle
     from repro.core.sparse_dbht import PANEL_ROWS  # noqa: F401
 
@@ -426,57 +428,63 @@ def _sparse_tail(cfg, n: int, tm: TMFGResult, w_sim, c_cap: int,
     rho = jnp.clip(w_sim.astype(jnp.float32), -1.0, 1.0)
     w_len = jnp.sqrt(jnp.maximum(2.0 * (1.0 - rho), 0.0))
     graph = csr_from_edges(n, edges, w_len)
-    hubs, D_h = apsp_mod.hub_factor_sparse(
-        graph, n_hubs=cfg.apsp_hubs, rounds=cfg.apsp_rounds,
-        backend=cfg.backend)
+    with jax.named_scope("apsp"):
+        hubs, D_h, rounds = apsp_mod.hub_factor_sparse_rounds(
+            graph, cfg.apsp_hubs, cfg.apsp_rounds)
 
-    direction = _device_directions_sparse(
-        n, edges, w_sim, tm.bubble_parent, tm.bubble_tri, tm.home_bubble)
-    _, dest, conv_mask = dbht_mod._device_flow(tm.bubble_parent, direction)
-    conv_id = jnp.cumsum(conv_mask.astype(jnp.int32)) - 1
-    bubble_cluster = conv_id[dest]
-    cluster_of = bubble_cluster[tm.home_bubble.astype(jnp.int32)]
+    with jax.named_scope("dbht"):
+        direction = _device_directions_sparse(
+            n, edges, w_sim, tm.bubble_parent, tm.bubble_tri,
+            tm.home_bubble)
+        _, dest, conv_mask = dbht_mod._device_flow(tm.bubble_parent,
+                                                   direction)
+        conv_id = jnp.cumsum(conv_mask.astype(jnp.int32)) - 1
+        bubble_cluster = conv_id[dest]
+        cluster_of = bubble_cluster[tm.home_bubble.astype(jnp.int32)]
 
-    bubble_of, dmax, ccm = _sweep_panels_device(
-        D_h, graph, tm.bubble_verts, bubble_cluster, cluster_of, c_cap, bm)
+        bubble_of, dmax, ccm = _sweep_panels_device(
+            D_h, graph, tm.bubble_verts, bubble_cluster, cluster_of, c_cap,
+            bm)
 
-    m1 = jnp.float32(2.0) * dmax                             # oracle's f32
-    m2 = jnp.float32(8.0) * dmax
-    off2 = m2 - m1
+    with jax.named_scope("hac"):
+        m1 = jnp.float32(2.0) * dmax                         # oracle's f32
+        m2 = jnp.float32(8.0) * dmax
+        off2 = m2 - m1
 
-    # member grouping: stable sort by cluster keeps members ascending
-    # within a cluster; slots ordered by minimum member (staged order)
-    v_order = jnp.argsort(cluster_of, stable=True).astype(jnp.int32)
-    counts = jax.ops.segment_sum(jnp.ones((n,), jnp.int32), cluster_of,
-                                 num_segments=c_cap)
-    bounds = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                              jnp.cumsum(counts)])
-    first = v_order[jnp.clip(bounds[:c_cap], 0, n - 1)]
-    min_member = jnp.where(counts > 0, first, n)             # empties last
-    perm = jnp.argsort(min_member).astype(jnp.int32)
+        # member grouping: stable sort by cluster keeps members ascending
+        # within a cluster; slots ordered by minimum member (staged order)
+        v_order = jnp.argsort(cluster_of, stable=True).astype(jnp.int32)
+        counts = jax.ops.segment_sum(jnp.ones((n,), jnp.int32), cluster_of,
+                                     num_segments=c_cap)
+        bounds = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                  jnp.cumsum(counts)])
+        first = v_order[jnp.clip(bounds[:c_cap], 0, n - 1)]
+        min_member = jnp.where(counts > 0, first, n)         # empties last
+        perm = jnp.argsort(min_member).astype(jnp.int32)
 
-    C_total = jnp.sum(conv_mask.astype(jnp.int32))
-    overflow = (C_total > c_cap) | (jnp.max(counts) > m_cap)
+        C_total = jnp.sum(conv_mask.astype(jnp.int32))
+        overflow = (C_total > c_cap) | (jnp.max(counts) > m_cap)
 
-    all_rows, members = _slot_hac(
-        D_h, graph, bubble_of, counts, bounds, perm, v_order, m1,
-        c_cap, m_cap, cfg.backend)
+        all_rows, members, rescans = _slot_hac(
+            D_h, graph, bubble_of, counts, bounds, perm, v_order, m1,
+            c_cap, m_cap, cfg.backend)
 
-    # top level over slots: cross-cluster maxima in perm order, the
-    # staged two-add offset, empty-slot pairs masked to +inf (their
-    # merges land after every real one — §14.5 pad invariance)
-    ccm_p = ccm[perm][:, perm]
-    sym = jnp.maximum(ccm_p, ccm_p.T)
-    top_adj = (sym + m1) + off2
-    sv = counts[perm] > 0
-    top_adj = jnp.where(sv[:, None] & sv[None, :], top_adj, INF)
-    Zt = hac_mod.complete_linkage(top_adj, backend="jnp")    # staged's jnp
-
-    Z = _assemble_device(n, all_rows, members, counts[perm], perm, Zt,
-                         c_cap, m_cap)
+        # top level over slots: cross-cluster maxima in perm order, the
+        # staged two-add offset, empty-slot pairs masked to +inf (their
+        # merges land after every real one — §14.5 pad invariance)
+        ccm_p = ccm[perm][:, perm]
+        sym = jnp.maximum(ccm_p, ccm_p.T)
+        top_adj = (sym + m1) + off2
+        sv = counts[perm] > 0
+        top_adj = jnp.where(sv[:, None] & sv[None, :], top_adj, INF)
+        Zt, top_rescans = hac_mod.complete_linkage_rescans(
+            top_adj, backend="jnp")                          # staged's jnp
+        Z = _assemble_device(n, all_rows, members, counts[perm], perm,
+                             Zt, c_cap, m_cap)
     return dict(direction=direction, conv_mask=conv_mask,
                 cluster_of=cluster_of, bubble_of=bubble_of, D=D_h, Z=Z,
-                hubs=hubs, overflow=overflow)
+                hubs=hubs, overflow=overflow, apsp_rounds=rounds,
+                hac_rescans=rescans + top_rescans)
 
 
 # ---------------------------------------------------------------------------
@@ -489,14 +497,17 @@ def _dense_tail(cfg, S, tm: TMFGResult):
     below HUB_MIN_N, or non-hub methods)."""
     from repro.core import dbht as dbht_mod
 
-    W = apsp_mod.edge_lengths(S.shape[0], tm.edges, S)
-    D = apsp_mod.apsp(W, method=cfg.apsp_method, n_hubs=cfg.apsp_hubs,
-                      rounds=cfg.apsp_rounds, backend=cfg.backend)
+    with jax.named_scope("apsp"):
+        W = apsp_mod.edge_lengths(S.shape[0], tm.edges, S)
+        D, rounds = apsp_mod.apsp_rounds(
+            W, method=cfg.apsp_method, n_hubs=cfg.apsp_hubs,
+            rounds=cfg.apsp_rounds, backend=cfg.backend)
     core = dbht_mod._dbht_device_core(
         S, tm.edges, tm.bubble_parent, tm.bubble_tri, tm.bubble_verts,
         tm.home_bubble, D, backend=cfg.backend)
     core["hubs"] = None
     core["overflow"] = None
+    core["apsp_rounds"] = rounds
     return core
 
 
@@ -535,17 +546,16 @@ def fused_from_table(cfg, n: int, *, from_x: bool = True,
     sparse = use_sparse_tail(cfg, n)
 
     def tail(tv, ti, src):
-        tm, w_edges, counters = sparse_lazy_tmfg(tv, ti, src,
-                                                 from_x=from_x)
+        with jax.named_scope("tmfg"):
+            tm, w_edges, counters = sparse_lazy_tmfg(tv, ti, src,
+                                                     from_x=from_x)
         if sparse:
             core = _sparse_tail(cfg, n, tm, w_edges, c_cap, m_cap, bm)
         else:
             S_use = adjacency_from_weights(n, tm.edges, w_edges) \
                 if from_x else src
             core = _dense_tail(cfg, S_use, tm)
-        core["tmfg"] = tm
-        core["counters"] = counters
-        return core
+        return _with_counters(core, n, tm, counters)
 
     return tail
 
@@ -572,28 +582,32 @@ def fused_one(cfg, have_S: bool, n: int,
         counters = None
         if not approx:
             # dense similarity + sparse APSP tail (§14.6 retired)
-            S = arr if have_S else ops.pearson(arr, backend=cfg.backend)
-            tm = build_tmfg(S, method=cfg.method, prefix=cfg.prefix,
-                            topk=cfg.topk)
+            with jax.named_scope("similarity"):
+                S = arr if have_S else ops.pearson(arr, backend=cfg.backend)
+            with jax.named_scope("tmfg"):
+                tm = build_tmfg(S, method=cfg.method, prefix=cfg.prefix,
+                                topk=cfg.topk)
             w_sim = S[tm.edges[:, 0], tm.edges[:, 1]]
             core = _sparse_tail(cfg, n, tm, w_sim, c_cap, m_cap, bm)
         else:
             kk = min(cfg.sim_k, n - 1)
-            if have_S:
-                # staged _topk_from_similarity's exact ops
-                S = arr.astype(jnp.float32)
-                Sd = jnp.where(jnp.eye(n, dtype=bool), -jnp.inf, S)
-                tv, ti = lax.top_k(Sd, kk)
-                ti = ti.astype(jnp.int32)
-                src, from_x = S, False
-            else:
-                tv, ti = ops.topk(arr, kk, backend=cfg.backend,
-                                  bm=128, bn=128)
-                src, from_x = standardize_rows(arr), True
-                S = None
+            with jax.named_scope("topk"):
+                if have_S:
+                    # staged _topk_from_similarity's exact ops
+                    S = arr.astype(jnp.float32)
+                    Sd = jnp.where(jnp.eye(n, dtype=bool), -jnp.inf, S)
+                    tv, ti = lax.top_k(Sd, kk)
+                    ti = ti.astype(jnp.int32)
+                    src, from_x = S, False
+                else:
+                    tv, ti = ops.topk(arr, kk, backend=cfg.backend,
+                                      bm=128, bn=128)
+                    src, from_x = standardize_rows(arr), True
+                    S = None
             if cfg.method == "lazy":
-                tm, w_edges, counters = sparse_lazy_tmfg(
-                    tv, ti, src, from_x=from_x)
+                with jax.named_scope("tmfg"):
+                    tm, w_edges, counters = sparse_lazy_tmfg(
+                        tv, ti, src, from_x=from_x)
                 if sparse:
                     core = _sparse_tail(cfg, n, tm, w_edges, c_cap,
                                         m_cap, bm)
@@ -605,17 +619,29 @@ def fused_one(cfg, have_S: bool, n: int,
                     core = _dense_tail(cfg, S_use, tm)
             else:
                 # non-lazy methods run on the densified table (§13.3)
-                Sd = _densify(tv, ti, n)
-                tm = build_tmfg(Sd, method=cfg.method, prefix=cfg.prefix,
-                                topk=cfg.topk)
+                with jax.named_scope("tmfg"):
+                    Sd = _densify(tv, ti, n)
+                    tm = build_tmfg(Sd, method=cfg.method,
+                                    prefix=cfg.prefix, topk=cfg.topk)
                 if sparse:
                     w_sim = Sd[tm.edges[:, 0], tm.edges[:, 1]]
                     core = _sparse_tail(cfg, n, tm, w_sim, c_cap,
                                         m_cap, bm)
                 else:
                     core = _dense_tail(cfg, Sd, tm)
-        core["tmfg"] = tm
-        core["counters"] = counters
-        return core
+        return _with_counters(core, n, tm, counters)
 
     return one
+
+
+def _with_counters(core: dict, n: int, tm: TMFGResult, sparse) -> dict:
+    """``core`` with the TMFG and the program's loop counters
+    (``pipeline.loop_counters``, DESIGN.md §15.5) under ``tmfg`` and
+    ``counters``; the tails' raw counts leave the dict."""
+    from repro.core.pipeline import loop_counters  # local: no import cycle
+
+    core["tmfg"] = tm
+    core["counters"] = loop_counters(
+        n, tm=tm, apsp_rounds=core.pop("apsp_rounds"),
+        hac_rescans=core.pop("hac_rescans"), sparse=sparse)
+    return core

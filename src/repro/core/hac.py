@@ -64,6 +64,14 @@ def complete_linkage(D: jax.Array, *, backend: str = "jnp") -> jax.Array:
     TMFG uses.  Both compare identical values with identical low-index
     tie-breaking, so the linkage is bitwise the same on every backend.
     """
+    return complete_linkage_rescans(D, backend=backend)[0]
+
+
+@functools.partial(jax.jit, static_argnames=("backend",))
+def complete_linkage_rescans(D: jax.Array, *, backend: str = "jnp"):
+    """:func:`complete_linkage` plus its rescan count: ``(Z, rescans)``,
+    the row-rescan loop's steps summed over the n-1 merges (each step
+    rescans up to ``RESCAN_ROWS`` rows; DESIGN.md §15.5)."""
     n = D.shape[0]
     D = D.astype(jnp.float32)
     D = jnp.where(jnp.eye(n, dtype=bool), INF, D)
@@ -75,23 +83,24 @@ def complete_linkage(D: jax.Array, *, backend: str = "jnp") -> jax.Array:
     Z = jnp.zeros((n - 1, 4), jnp.float32)
     nv, nc = _nearest(D, alive, backend)
 
-    def rescan(D, alive, stale, nv, nc):
+    def rescan(D, alive, stale, nv, nc, steps):
         def pending(c):
             return jnp.any(c[0])
 
         def step(c):
-            stale, nv, nc = c
+            stale, nv, nc, steps = c
             rows = jnp.nonzero(stale, size=RESCAN_ROWS, fill_value=-1)[0]
             rows = jnp.where(rows >= 0, rows, rows[0])    # pad: repeat one
             v, col = _nearest(D[rows], alive, backend)
             return (stale.at[rows].set(False), nv.at[rows].set(v),
-                    nc.at[rows].set(col))
+                    nc.at[rows].set(col), steps + 1)
 
-        _, nv, nc = jax.lax.while_loop(pending, step, (stale, nv, nc))
-        return nv, nc
+        _, nv, nc, steps = jax.lax.while_loop(pending, step,
+                                              (stale, nv, nc, steps))
+        return nv, nc, steps
 
     def body(k, carry):
-        D, ids, sizes, alive, nv, nc, Z = carry
+        D, ids, sizes, alive, nv, nc, Z, steps = carry
         vals = jnp.where(alive, nv, -INF)
         i = jnp.argmax(vals).astype(jnp.int32)    # lowest row, then column
         h = -vals[i]
@@ -111,12 +120,13 @@ def complete_linkage(D: jax.Array, *, backend: str = "jnp") -> jax.Array:
         ids = ids.at[i].set(n + k)
         sizes = sizes.at[i].set(sizes[i] + sizes[j])
         stale = alive & ((nc == i) | (nc == j) | (rows_n == i))
-        nv, nc = rescan(D, alive, stale, nv, nc)
-        return D, ids, sizes, alive, nv, nc, Z
+        nv, nc, steps = rescan(D, alive, stale, nv, nc, steps)
+        return D, ids, sizes, alive, nv, nc, Z, steps
 
     carry = jax.lax.fori_loop(
-        0, n - 1, body, (D, class_ids, sizes, alive, nv, nc, Z))
-    return carry[-1]
+        0, n - 1, body,
+        (D, class_ids, sizes, alive, nv, nc, Z, jnp.int32(0)))
+    return carry[-2], carry[-1]
 
 
 def hierarchical_offsets(D: jax.Array, bubble_of: jax.Array,

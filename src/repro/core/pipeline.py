@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import functools
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Dict, List, NamedTuple, Optional
 
@@ -62,9 +63,11 @@ from .tmfg import TMFGResult, adjacency_from_weights, build_tmfg
 def _observe_stage(stage: str, seconds: float) -> None:
     """Per-stage latency into the process-global registry (DESIGN.md
     §15.3); the staged path's spans feed it, so `ClusterService.stats()`
-    exports the same numbers `ClusterResult.timings` reports."""
+    exports the same numbers `ClusterResult.timings` reports, and so do
+    the fused path's stage marks when they are on (§15.5)."""
     obs_metrics.histogram("pipeline_stage_seconds",
-                          "staged-path per-stage latency (fenced)",
+                          "per-stage latency (fenced staged spans, or "
+                          "the fused path's stage marks)",
                           stage=stage).observe(seconds)
 
 
@@ -72,6 +75,85 @@ def _observe_total(path: str, seconds: float) -> None:
     obs_metrics.histogram("pipeline_total_seconds",
                           "end-to-end cluster()/cluster_batch() latency",
                           path=path).observe(seconds)
+
+
+# the fused path's host phases (DESIGN.md §15.5), in call order, and the
+# stage marks of the dense body when built under tracing
+PHASES = ("put", "dispatch", "device", "assemble")
+STAGES = ("similarity", "tmfg", "apsp", "dbht", "hac")
+
+
+@contextmanager
+def _phase(call: obs_trace.Span, phase: str):
+    """One host phase of a fused call: a span under ``pipeline.fused``,
+    its seconds on the call's record (``<phase>_s``) and in the registry
+    histogram ``pipeline_phase_seconds{phase=...}``."""
+    with obs_trace.span(f"pipeline.{phase}") as sp:
+        yield sp
+    call.attrs[f"{phase}_s"] = sp.duration
+    obs_metrics.histogram("pipeline_phase_seconds",
+                          "fused-path host phase latency",
+                          phase=phase).observe(sp.duration)
+
+
+def loop_counters(n: int, *, apsp_rounds, hac_rescans, tm=None,
+                  sparse=None) -> Dict[str, jax.Array]:
+    """The fused program's loop counters for one problem (DESIGN.md
+    §15.5), keyed by their registry names less ``_total``: the lazy
+    TMFG's pops and inserts (when the filter is a TMFG), the APSP
+    rounds, the HAC rescans and merges, and the approx scan's lookups
+    (``sparse``, a ``SparseCounters``)."""
+    c = {}
+    if tm is not None:
+        c["tmfg_pops"] = tm.pops
+        c["tmfg_inserts"] = jnp.int32(n - 4)
+    c["apsp_rounds"] = jnp.asarray(apsp_rounds, jnp.int32)
+    c["hac_rescans"] = hac_rescans
+    c["hac_merges"] = jnp.int32(n - 1)
+    if sparse is not None:
+        c["approx_lookups"] = sparse.lookups
+        c["approx_fallbacks"] = sparse.fallbacks
+        c["approx_pair_lookups"] = sparse.pair_lookups
+        c["approx_pair_misses"] = sparse.pair_misses
+    return c
+
+
+def _record_counters(counters, problems: int) -> Dict[str, float]:
+    """Sum a call's counters (host copies, pads already cut away) into
+    the registry; returns the sums, with ``problems``."""
+    sums = {"problems": float(problems)}
+    obs_metrics.counter("pipeline_problems_total").inc(problems)
+    for name, v in (counters or {}).items():
+        sums[name] = float(np.sum(np.asarray(v)))
+        obs_metrics.counter(f"{name}_total").inc(sums[name])
+    return sums
+
+
+def _approx_timings(sums: Dict[str, float]) -> Dict[str, float]:
+    """The approx scan's diagnostics as ``timings`` keys (§13.3)."""
+    if "approx_lookups" not in sums:
+        return {}
+    fb = sums["approx_fallbacks"]
+    return {"sim_fallbacks": fb,
+            "sim_fallback_rate": fb / max(sums["approx_lookups"], 1.0),
+            "sim_pair_misses": sums["approx_pair_misses"]}
+
+
+def _stage_seconds(marks) -> Dict[str, float]:
+    """Seconds per stage from one call's marks: each stage runs from the
+    previous mark (``start`` for the first) to its own, the latest where
+    several devices fired one."""
+    ends: Dict[str, float] = {}
+    for stage, t in marks:
+        ends[stage] = max(t, ends.get(stage, t))
+    if not all(s in ends for s in ("start",) + STAGES):
+        return {}
+    prev = min(t for stage, t in marks if stage == "start")
+    out = {}
+    for stage in STAGES:
+        out[stage] = ends[stage] - prev
+        prev = ends[stage]
+    return out
 
 
 @dataclass
@@ -122,10 +204,11 @@ class DeviceOutputs(NamedTuple):
     plus the DBHT stage outputs, one pytree = one host transfer.
     Batched runs carry a leading batch axis on every leaf.
 
-    The last three fields exist only on the fused sparse/approx program
-    (DESIGN.md §17) and default to ``None`` — an empty pytree subtree,
-    so the dense program's pytree and its cached executables are
-    unchanged."""
+    ``hubs`` and ``overflow`` exist only on the fused sparse/approx
+    program (DESIGN.md §17) and default to ``None`` — an empty pytree
+    subtree.  ``counters`` is every fused program's dict of per-problem
+    loop counters (:func:`loop_counters`, DESIGN.md §15.5), scalars that
+    ride the one transfer."""
 
     tmfg: TMFGResult          # fixed-shape TMFG arrays
     direction: jax.Array      # (B_,) bubble-tree edge directions ([0] unused)
@@ -136,36 +219,51 @@ class DeviceOutputs(NamedTuple):
     linkage: jax.Array        # the sparse tail; (n-1, 4) dendrogram
     hubs: Optional[jax.Array] = None      # (h,) hub ids (sparse tail)
     overflow: Optional[jax.Array] = None  # bool: slot-grid caps exceeded
-    counters: Optional[object] = None     # SparseCounters (approx only)
+    counters: Optional[dict] = None       # loop counters (loop_counters)
 
 
-def _fused_one(cfg: PipelineConfig, have_S: bool):
+def _fused_one(cfg: PipelineConfig, have_S: bool, marks: bool = False):
     """The traceable single-matrix pipeline body for ``cfg``.
 
     Composes exactly the stages the staged path runs — ops.pearson,
     build_tmfg, apsp.edge_lengths + apsp, the device DBHT core and the
     nested HAC — so fused and staged outputs are identical (the §12.2
-    parity contract, pinned by tests/test_fused.py)."""
+    parity contract, pinned by tests/test_fused.py).  Each stage is a
+    named scope; with ``marks`` each stage boundary is also a host-clock
+    mark (DESIGN.md §15.5)."""
 
     def one(arr):
-        S = arr if have_S else ops.pearson(arr, backend=cfg.backend)
-        if cfg.clean == "rmt":
-            # §18.2: eigenvalue clipping changes ONLY the similarity
-            # input; T is the (static) window length of the series
-            from repro.filters import rmt as rmt_mod  # lazy: no cycle
-            S = rmt_mod.clean(S, arr.shape[-1])
-        tm = build_tmfg(S, method=cfg.method, prefix=cfg.prefix,
-                        topk=cfg.topk)
-        W = apsp_mod.edge_lengths(S.shape[0], tm.edges, S)
-        D = apsp_mod.apsp(W, method=cfg.apsp_method, n_hubs=cfg.apsp_hubs,
-                          rounds=cfg.apsp_rounds, backend=cfg.backend)
+        n = arr.shape[0]
+        mark = obs_trace.StageMarks(marks)
+        arr = mark("start", arr)
+        with jax.named_scope("similarity"):
+            S = arr if have_S else ops.pearson(arr, backend=cfg.backend)
+            if cfg.clean == "rmt":
+                # §18.2: eigenvalue clipping changes ONLY the similarity
+                # input; T is the (static) window length of the series
+                from repro.filters import rmt as rmt_mod  # lazy: no cycle
+                S = rmt_mod.clean(S, arr.shape[-1])
+        S = mark("similarity", S)
+        with jax.named_scope("tmfg"):
+            tm = build_tmfg(S, method=cfg.method, prefix=cfg.prefix,
+                            topk=cfg.topk)
+        S, tm = mark("tmfg", (S, tm))
+        with jax.named_scope("apsp"):
+            W = apsp_mod.edge_lengths(n, tm.edges, S)
+            D, rounds = apsp_mod.apsp_rounds(
+                W, method=cfg.apsp_method, n_hubs=cfg.apsp_hubs,
+                rounds=cfg.apsp_rounds, backend=cfg.backend)
+        S, tm, D = mark("apsp", (S, tm, D))
         core = dbht_mod._dbht_device_core(
             S, tm.edges, tm.bubble_parent, tm.bubble_tri, tm.bubble_verts,
-            tm.home_bubble, D, backend=cfg.backend)
+            tm.home_bubble, D, backend=cfg.backend, mark=mark)
+        Z = mark("hac", core["Z"])
         return DeviceOutputs(
             tmfg=tm, direction=core["direction"], conv_mask=core["conv_mask"],
             cluster_of=core["cluster_of"], bubble_of=core["bubble_of"],
-            apsp=core["D"], linkage=core["Z"])
+            apsp=core["D"], linkage=Z,
+            counters=loop_counters(n, tm=tm, apsp_rounds=rounds,
+                                   hac_rescans=core["hac_rescans"]))
 
     return one
 
@@ -188,10 +286,12 @@ def _fused_filter_one(cfg: PipelineConfig, have_S: bool):
     from repro import filters as filt  # lazy: no import cycle
 
     def one(arr):
-        S = arr if have_S else ops.pearson(arr, backend=cfg.backend)
-        if cfg.clean == "rmt":
-            S = filt.rmt.clean(S, arr.shape[-1])
-        fg = filt.build_filter(S, cfg)
+        with jax.named_scope("similarity"):
+            S = arr if have_S else ops.pearson(arr, backend=cfg.backend)
+            if cfg.clean == "rmt":
+                S = filt.rmt.clean(S, arr.shape[-1])
+        with jax.named_scope("tmfg"):
+            fg = filt.build_filter(S, cfg)
         core = filt.filter_tail(S, fg, apsp_method=cfg.apsp_method,
                                 apsp_hubs=cfg.apsp_hubs,
                                 apsp_rounds=cfg.apsp_rounds,
@@ -199,7 +299,10 @@ def _fused_filter_one(cfg: PipelineConfig, have_S: bool):
         return DeviceOutputs(
             tmfg=fg, direction=core["direction"],
             conv_mask=core["conv_mask"], cluster_of=core["cluster_of"],
-            bubble_of=core["bubble_of"], apsp=core["D"], linkage=core["Z"])
+            bubble_of=core["bubble_of"], apsp=core["D"], linkage=core["Z"],
+            counters=loop_counters(arr.shape[0],
+                                   apsp_rounds=core["apsp_rounds"],
+                                   hac_rescans=core["hac_rescans"]))
 
     return one
 
@@ -248,6 +351,10 @@ def run_pipeline_device(X_or_S, config: PipelineConfig, *,
     split by hand, or every device runs the whole batch when B does not
     divide the mesh).
 
+    While tracing is on (``obs.trace.enable``/``tracing``) when the
+    program is built, the dense body also takes a host-clock mark at
+    each stage boundary (DESIGN.md §15.5).
+
     Returns :class:`DeviceOutputs` — device arrays, NO host transfer:
     callers choose what crosses the boundary (``cluster`` transfers
     everything once; the stream scheduler's pad entries never do).
@@ -288,14 +395,20 @@ def run_pipeline_device(X_or_S, config: PipelineConfig, *,
                 f"square input {arr.shape} is not symmetric, so it is "
                 f"ambiguous: pass is_similarity= explicitly")
 
+    # stage marks are host callbacks, and JAX writes no persistent-cache
+    # entry for a program with one: only a program built while tracing
+    # is on carries them, under a key of its own (DESIGN.md §15.5)
+    dense = config.filter == "tmfg" and not _needs_approx_body(config)
+    marks = dense and obs_trace.enabled()
+
     def build():
         if config.filter != "tmfg":
             one = _fused_filter_one(config, is_similarity)
-        elif _needs_approx_body(config):
+        elif not dense:
             one = _fused_approx_one(config, is_similarity,
                                     int(arr.shape[-2]), caps)
         else:
-            one = _fused_one(config, is_similarity)
+            one = _fused_one(config, is_similarity, marks)
         if not batched:
             return jax.jit(one)
         if mesh is None:
@@ -305,7 +418,8 @@ def run_pipeline_device(X_or_S, config: PipelineConfig, *,
                                      in_specs=spec, out_specs=P(*spec[:1]),
                                      check_vma=False))
 
-    key = ("fused", config, is_similarity, batched, arr.shape, caps, mesh)
+    key = ("fused", config, is_similarity, batched, arr.shape, caps, mesh,
+           marks)
     # the runtime recompile watchdog (DESIGN.md §15.2): a key already in
     # the executable cache is a REPLAY — if XLA compiles a new program
     # under it anyway, that is the BENCH_5 failure mode happening in
@@ -319,6 +433,35 @@ def run_pipeline_device(X_or_S, config: PipelineConfig, *,
             detail="replayed fused executable lowered a new program",
             shape=str(arr.shape), batched=batched)
     return out
+
+
+def _fused_run(call: obs_trace.Span, arr, cfg: PipelineConfig,
+               have_S: bool, batched: bool, mesh,
+               B_out: Optional[int]) -> Optional[DeviceOutputs]:
+    """The dispatch and device phases of a fused call (DESIGN.md §15.5):
+    host copies of the program's outputs (the first ``B_out`` entries of
+    a batch), or None when the program overflowed its slot-grid caps
+    (§17.3).  Records on ``call`` whether the dispatch compiled, and the
+    stage seconds when the program carries stage marks."""
+    obs_trace.take_marks()                  # drop marks of earlier calls
+    with _phase(call, "dispatch") as sp_dispatch:
+        out = run_pipeline_device(arr, cfg, is_similarity=have_S,
+                                  batched=batched, mesh=mesh)
+    call.attrs["compiled"] = sp_dispatch.compiles > 0
+    with _phase(call, "device"):
+        if B_out is not None:
+            # sliced to B_out first so pad entries of a bucketed
+            # micro-batch never cross the boundary
+            out = jax.tree.map(lambda a: a[:B_out], out)
+        host = jax.device_get(out)
+        overflow = host.overflow is not None and bool(
+            np.any(np.asarray(host.overflow)))
+    stages = _stage_seconds(obs_trace.take_marks())
+    if stages:
+        call.attrs["stages"] = stages
+        for stage, secs in stages.items():
+            _observe_stage(stage, secs)
+    return None if overflow else host
 
 
 def _result_from_fused(host: DeviceOutputs, b: Optional[int] = None,
@@ -420,23 +563,28 @@ def cluster(X=None, *, S=None, moments=None, k: Optional[int] = None,
 
     if fused:
         # fence=False: the fused path's one device_get IS its sync —
-        # the span adds no block_until_ready (the §15.1 zero-cost
-        # contract, pinned by tests/test_obs.py), and its duration is
-        # device-true anyway because the transfer waits for the program
-        with obs_trace.span("pipeline.fused", fence=False) as sp:
-            if S is not None:
-                arr, have_S = jnp.asarray(S, jnp.float32), True
-            elif moments is not None:
-                from repro.stream.window import window_similarity  # no cycle
-                arr, have_S = window_similarity(moments), True
-            else:
-                assert X is not None, "need X, S or moments"
-                arr, have_S = jnp.asarray(np.asarray(X), jnp.float32), False
-            out = run_pipeline_device(arr, cfg, is_similarity=have_S,
-                                      batched=False, mesh=mesh)
-            host = jax.device_get(out)
-        if host.overflow is not None and bool(np.any(np.asarray(
-                host.overflow))):
+        # the spans add no block_until_ready (the §15.1 zero-cost
+        # contract, pinned by tests/test_obs.py), and the device phase
+        # is device-true anyway because the transfer waits for the program
+        with obs_trace.span("pipeline.fused", fence=False, keep=True,
+                            batch=1) as sp:
+            with _phase(sp, "put"):
+                if S is not None:
+                    arr, have_S = jnp.asarray(S, jnp.float32), True
+                elif moments is not None:
+                    from repro.stream.window import window_similarity
+                    arr, have_S = window_similarity(moments), True
+                else:
+                    assert X is not None, "need X, S or moments"
+                    arr = jnp.asarray(np.asarray(X), jnp.float32)
+                    have_S = False
+            host = _fused_run(sp, arr, cfg, have_S, False, mesh, None)
+            if host is not None:
+                with _phase(sp, "assemble"):
+                    sums = _record_counters(host.counters, 1)
+                    res = _result_from_fused(host, k=k)
+                sp.attrs.update(sums)
+        if host is None:
             # the partition exceeded the fused slot-grid caps (§17.3):
             # the staged sparse tail sizes its programs per cluster, so
             # it is correct at any partition — rerun there, and say so
@@ -445,22 +593,9 @@ def cluster(X=None, *, S=None, moments=None, k: Optional[int] = None,
             res.overflow = True
             return res
         _observe_total("fused", sp.duration)
-        timings = {"total": sp.duration}
-        if host.counters is not None:
-            # same diagnostics the staged approx path surfaces (§13.3),
-            # materialized with the one fused transfer
-            lk = int(host.counters.lookups)
-            fb = int(host.counters.fallbacks)
-            pm = int(host.counters.pair_misses)
-            obs_metrics.counter("approx_lookups_total").inc(lk)
-            obs_metrics.counter("approx_fallbacks_total").inc(fb)
-            obs_metrics.counter("approx_pair_misses_total").inc(pm)
-            if collect_timings:
-                timings["sim_fallbacks"] = float(fb)
-                timings["sim_fallback_rate"] = fb / max(lk, 1)
-                timings["sim_pair_misses"] = float(pm)
-        return _result_from_fused(
-            host, k=k, timings=timings if collect_timings else None)
+        if collect_timings:
+            res.timings = {"total": sp.duration, **_approx_timings(sums)}
+        return res
 
     # ---- staged path: per-stage jits + syncs (DESIGN.md §12.4) ----------
     if cfg.filter != "tmfg":
@@ -866,35 +1001,41 @@ def cluster_batch(X=None, *, S=None, k: Optional[int] = None,
             "DESIGN.md §12.4)")
 
     timings: Dict[str, float] = {}
-    if S is None:
-        assert X is not None, "need X or S"
-        arr, have_S = jnp.asarray(X, dtype=jnp.float32), False
-    else:
-        arr, have_S = jnp.asarray(S, dtype=jnp.float32), True
-    assert arr.ndim == 3, f"batched input must be 3-D, got {arr.shape}"
+    assert X is not None or S is not None, "need X or S"
+    have_S = S is not None
+    shape = np.shape(S if have_S else X)
+    assert len(shape) == 3, f"batched input must be 3-D, got {shape}"
     assert limit is None or limit >= 1, f"limit must be >= 1, got {limit}"
-    B = arr.shape[0]
+    B = shape[0]
     B_out = B if limit is None else min(limit, B)
 
-    # place the batch over the mesh's data axes when it divides them;
-    # otherwise stay on the default device (single-device fallback)
-    n_dev = len(jax.devices())
-    if mesh is None and n_dev > 1 and B % n_dev == 0:
-        mesh = dist_sh.data_mesh()
-    if mesh is not None:
-        arr = jax.device_put(arr, dist_sh.batch_shardings(mesh, arr))
+    def put(mesh):
+        arr = jnp.asarray(S if have_S else X, dtype=jnp.float32)
+        # place the batch over the mesh's data axes when it divides
+        # them; otherwise stay on the default device (single-device
+        # fallback)
+        n_dev = len(jax.devices())
+        if mesh is None and n_dev > 1 and B % n_dev == 0:
+            mesh = dist_sh.data_mesh()
+        if mesh is not None:
+            arr = jax.device_put(arr, dist_sh.batch_shardings(mesh, arr))
+        return arr, mesh
 
     if fused:
-        # unfenced span (§15.1): the sliced device_get is the one sync
-        with obs_trace.span("pipeline.fused", fence=False,
+        # unfenced spans (§15.1): the sliced device_get is the one sync
+        with obs_trace.span("pipeline.fused", fence=False, keep=True,
                             batch=B) as sp:
-            out = run_pipeline_device(arr, cfg, is_similarity=have_S,
-                                      batched=True, mesh=mesh)
-            # ONE transfer, sliced to B_out first so pad entries of a
-            # bucketed micro-batch never cross the boundary
-            host = jax.device_get(jax.tree.map(lambda a: a[:B_out], out))
-        if host.overflow is not None and bool(np.any(np.asarray(
-                host.overflow))):
+            with _phase(sp, "put"):
+                arr, mesh = put(mesh)
+            host = _fused_run(sp, arr, cfg, have_S, True, mesh, B_out)
+            if host is not None:
+                with _phase(sp, "assemble"):
+                    sums = _record_counters(host.counters, B_out)
+                    results = [_result_from_fused(host, b=b, k=k)
+                               for b in range(B_out)]
+                    labels = np.stack([r.labels for r in results])
+                sp.attrs.update(sums)
+        if host is None:
             # any entry past the fused slot-grid caps (§17.3) sends the
             # whole batch to the staged path (per-cluster-sized programs)
             out = cluster_batch(X, S=S, k=k, config=cfg, mesh=mesh,
@@ -905,27 +1046,17 @@ def cluster_batch(X=None, *, S=None, k: Optional[int] = None,
             return out
         total = sp.duration
         _observe_total("fused", total)
-        if host.counters is not None:
-            # batch-summed diagnostics, as on the staged path (§13.3)
-            lk = float(np.sum(np.asarray(host.counters.lookups)))
-            fb = float(np.sum(np.asarray(host.counters.fallbacks)))
-            pm = float(np.sum(np.asarray(host.counters.pair_misses)))
-            obs_metrics.counter("approx_lookups_total").inc(lk)
-            obs_metrics.counter("approx_fallbacks_total").inc(fb)
-            obs_metrics.counter("approx_pair_misses_total").inc(pm)
-            if collect_timings:
-                timings["sim_fallbacks"] = fb
-                timings["sim_fallback_rate"] = fb / max(lk, 1.0)
-                timings["sim_pair_misses"] = pm
-        per = {"total": total / B}
-        results = [
-            _result_from_fused(host, b=b, k=k,
-                               timings=dict(per) if collect_timings else None)
-            for b in range(B_out)]
-        timings["total"] = total
-        return BatchClusterResult(
-            labels=np.stack([r.labels for r in results]), results=results,
-            timings=timings if collect_timings else {})
+        if collect_timings:
+            # the batch-summed approx diagnostics (§13.3), and the call's
+            # time spread evenly over its B entries
+            timings.update(_approx_timings(sums))
+            timings["total"] = total
+            for r in results:
+                r.timings = {"total": total / B}
+        return BatchClusterResult(labels=labels, results=results,
+                                  timings=timings)
+
+    arr, mesh = put(mesh)
 
     # ---- staged path (DESIGN.md §12.4) ----------------------------------
     # same fenced-span structure as single-matrix cluster() (§15.1):

@@ -55,23 +55,24 @@ def _edge_metric(S: jax.Array, edges: jax.Array) -> jax.Array:
 
 
 def _distances(S: jax.Array, edges: jax.Array, *, apsp_method: str,
-               apsp_hubs: int, apsp_rounds: int, backend: str) -> jax.Array:
-    """Geodesic distances on the filtered graph, by ``apsp_method``."""
+               apsp_hubs: int, apsp_rounds: int, backend: str):
+    """Geodesic distances on the filtered graph, by ``apsp_method``, and
+    the rounds they took (squarings, or relaxation rounds)."""
     n = S.shape[0]
     if apsp_method == "exact" or (apsp_method == "hub"
                                   and n < apsp_mod.HUB_MIN_N):
         W = apsp_mod.edge_lengths(n, edges, S)
-        return apsp_mod.apsp_exact(W, backend=backend)
+        return apsp_mod.apsp_rounds(W, method="exact", backend=backend)
     # hub/sparse: the PR 6 edge-list factorization on the filter's edges
     d = _edge_metric(S, edges)
     graph = sparse_kernels.csr_from_edges(n, edges, d)
-    _, D_h = apsp_mod.hub_factor_sparse(graph, n_hubs=apsp_hubs,
-                                        rounds=apsp_rounds, backend=backend)
+    _, D_h, rounds = apsp_mod.hub_factor_sparse_rounds(graph, apsp_hubs,
+                                                       apsp_rounds)
     est = ops.minplus(D_h.T, D_h, backend=backend)
     est = est.at[edges[:, 0], edges[:, 1]].min(d)
     est = est.at[edges[:, 1], edges[:, 0]].min(d)
     est = jnp.minimum(est, est.T)
-    return est.at[jnp.arange(n), jnp.arange(n)].set(0.0)
+    return est.at[jnp.arange(n), jnp.arange(n)].set(0.0), rounds
 
 
 def _components(n: int, edges: jax.Array) -> jax.Array:
@@ -106,16 +107,23 @@ def filter_tail(S: jax.Array, fg: FilterGraph, *, apsp_method: str = "exact",
     ``bubble_of`` both hold the component id (there is no finer bubble
     level without a bubble tree), and ``direction`` is a length-1
     placeholder (its ``[1:]`` slice — the API surface — is empty).
+    ``apsp_rounds`` and ``hac_rescans`` are the tail's loop counters
+    (DESIGN.md §15.5).
     """
     n = S.shape[0]
-    D = _distances(S, fg.edges, apsp_method=apsp_method,
-                   apsp_hubs=apsp_hubs, apsp_rounds=apsp_rounds,
-                   backend=backend)
-    lab = _components(n, fg.edges)
-    conv_mask = lab == jnp.arange(n, dtype=jnp.int32)
-    comp_id = (jnp.cumsum(conv_mask.astype(jnp.int32)) - 1).astype(jnp.int32)
-    cluster_of = comp_id[lab]
-    adj = hac_mod.hierarchical_offsets(D, cluster_of, cluster_of)
-    Z = hac_mod.complete_linkage(adj, backend=backend)
+    with jax.named_scope("apsp"):
+        D, rounds = _distances(S, fg.edges, apsp_method=apsp_method,
+                               apsp_hubs=apsp_hubs, apsp_rounds=apsp_rounds,
+                               backend=backend)
+    with jax.named_scope("dbht"):
+        lab = _components(n, fg.edges)
+        conv_mask = lab == jnp.arange(n, dtype=jnp.int32)
+        comp_id = (jnp.cumsum(conv_mask.astype(jnp.int32))
+                   - 1).astype(jnp.int32)
+        cluster_of = comp_id[lab]
+        adj = hac_mod.hierarchical_offsets(D, cluster_of, cluster_of)
+    with jax.named_scope("hac"):
+        Z, rescans = hac_mod.complete_linkage_rescans(adj, backend=backend)
     return dict(direction=jnp.zeros((1,), jnp.float32), conv_mask=conv_mask,
-                cluster_of=cluster_of, bubble_of=cluster_of, D=D, Z=Z)
+                cluster_of=cluster_of, bubble_of=cluster_of, D=D, Z=Z,
+                apsp_rounds=rounds, hac_rescans=rescans)

@@ -127,7 +127,15 @@ def sparse_apsp_sources(graph: CSRGraph, sources: jax.Array, *,
     a fixed small cap (the old 32 default) left ``inf`` in every entry
     farther than 32 hops from its source — TMFG hop-diameters pass 32
     from n ≈ 1000, which shattered the sparse DBHT geometry downstream.
+    ``backend`` is accepted and ignored (one XLA form, module docstring).
     """
+    del backend
+    return relax_to_fixed_point(graph, sources, rounds)[0]
+
+
+def relax_to_fixed_point(graph: CSRGraph, sources: jax.Array, rounds: int):
+    """:func:`sparse_apsp_sources`' loop, plus the rounds it ran:
+    ``(D (s, n), rounds_run)``; traceable inside a caller's jit."""
     n = graph.n
     s = sources.shape[0]
     cap = rounds if rounds else n
@@ -140,8 +148,8 @@ def sparse_apsp_sources(graph: CSRGraph, sources: jax.Array, *,
 
     def body(carry):
         i, D, _ = carry
-        D2 = sparse_relax(D, graph, backend=backend)
+        D2 = sparse_relax(D, graph)
         return i + 1, D2, jnp.any(D2 < D)
 
-    _, D, _ = lax.while_loop(cond, body, (0, D0, jnp.bool_(True)))
-    return D
+    i, D, _ = lax.while_loop(cond, body, (0, D0, jnp.bool_(True)))
+    return D, i

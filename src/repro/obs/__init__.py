@@ -7,25 +7,25 @@ The one observability layer for the TMFG-DBHT pipeline:
   the recompile watchdog (§15.1–§15.2).
 * :mod:`repro.obs.metrics` — the process-global registry of counters /
   gauges / histograms every subsystem reports into (§15.3).
-* :mod:`repro.obs.export` — Prometheus text ``render``, JSON-lines
-  dump, and the ``jax.profiler`` deep-dive context (§15.4).
+* :mod:`repro.obs.export` — Prometheus text ``render`` and the
+  ``jax.profiler`` deep-dive context (§15.4).
 """
 
 from . import export, metrics, trace
-from .export import dump_jsonl, profile, render
+from .export import profile, render
 from .metrics import (REGISTRY, Registry, counter, gauge, histogram,
                       register_collector, reset, snapshot)
-from .trace import (Span, clear, compile_stats, disable, enable, enabled,
-                    events, record_event, record_recompile,
-                    recompile_events, span, spans, tracing,
+from .trace import (Span, StageMarks, clear, compile_stats, disable,
+                    enable, enabled, kept_spans, record_recompile,
+                    recompile_events, span, spans, take_marks, tracing,
                     watch_recompiles)
 
 __all__ = [
     "trace", "metrics", "export",
-    "Span", "span", "spans", "events", "tracing", "enable", "disable",
-    "enabled", "clear", "record_event", "watch_recompiles",
+    "Span", "span", "spans", "kept_spans", "tracing", "enable", "disable",
+    "enabled", "clear", "StageMarks", "take_marks", "watch_recompiles",
     "compile_stats", "record_recompile", "recompile_events",
     "REGISTRY", "Registry", "counter", "gauge", "histogram",
     "register_collector", "snapshot", "reset",
-    "render", "dump_jsonl", "profile",
+    "render", "profile",
 ]

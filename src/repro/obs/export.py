@@ -1,15 +1,12 @@
-"""Telemetry export: Prometheus text, JSON-lines, profiler (§15.4).
+"""Telemetry export: Prometheus text and the profiler (§15.4).
 
-Three ways out of the process for what `obs.trace` / `obs.metrics`
+Two ways out of the process for what `obs.trace` / `obs.metrics`
 collected (DESIGN.md §15.4):
 
 * :func:`render` — the registry in Prometheus text exposition format
   (``# HELP``/``# TYPE`` + samples, histograms as cumulative
   ``_bucket``/``_sum``/``_count``).  Deterministically ordered, so the
   output is golden-testable (tests/test_obs.py) and diffable.
-* :func:`dump_jsonl` — spans, events and a metrics snapshot as one
-  JSON object per line: the flight-recorder artifact a bench or an
-  incident dump attaches.
 * :func:`profile` — a ``jax.profiler.trace`` context manager for deep
   dives (per-op device timelines in TensorBoard/Perfetto), for when
   span granularity is not enough.
@@ -17,8 +14,6 @@ collected (DESIGN.md §15.4):
 
 from __future__ import annotations
 
-import json
-import time
 from contextlib import contextmanager
 from typing import Optional
 
@@ -66,37 +61,21 @@ def _num(v: float) -> str:
     return str(int(f)) if f == int(f) else repr(f)
 
 
-def dump_jsonl(path: str, *, registry: Optional[_metrics.Registry] = None,
-               include_spans: bool = True,
-               include_metrics: bool = True) -> int:
-    """Write collected spans/events + a metrics snapshot as JSON lines;
-    returns the number of lines written."""
-    reg = registry if registry is not None else _metrics.REGISTRY
-    lines = []
-    if include_spans:
-        for sp in _trace.spans():
-            lines.append(sp.to_dict())
-        lines.extend(_trace.events())
-        lines.extend(_trace.recompile_events())
-    if include_metrics:
-        lines.append(dict(kind="metrics", t=time.time(),
-                          samples=reg.snapshot(),
-                          compile=_trace.compile_stats()))
-    with open(path, "w") as f:
-        for obj in lines:
-            f.write(json.dumps(obj, default=str) + "\n")
-    return len(lines)
-
-
 @contextmanager
 def profile(logdir: str, *, create_perfetto_trace: bool = False):
     """Deep-dive profiler context: wraps ``jax.profiler.trace`` so a
     caller can capture per-op device timelines around any pipeline
     region (DESIGN.md §15.4).  Span tracing is enabled for the region
-    as well, so the coarse spans land next to the deep trace."""
+    as well, so the program's spans land in the deep trace as
+    annotations on its own clock; Python call tracing is off, so the
+    trace holds the device timeline and those spans, not every Python
+    frame."""
     import jax
 
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
     with _trace.tracing():
         with jax.profiler.trace(
-                logdir, create_perfetto_trace=create_perfetto_trace):
+                logdir, create_perfetto_trace=create_perfetto_trace,
+                profiler_options=opts):
             yield

@@ -27,6 +27,16 @@ everything else now routes through:
   runtime watchdog's alarm: the pipeline calls it whenever a *replayed*
   (config, shape) executable lowers a new program — the event lands in
   an always-on bounded log surfaced by ``ClusterService.healthz()``.
+* the kept ring (DESIGN.md §15.1) — spans opened with ``keep=True``
+  (one ``pipeline.fused`` record per fused call, carrying the call's
+  phase seconds and loop counters as attributes) are held in a small
+  bounded ring even while tracing is off, so a reader can pick out the
+  calls of any window afterwards (:func:`kept_spans`).
+* opt-in stage marks (DESIGN.md §15.5) — :class:`StageMarks` puts a
+  host-clock mark at each stage boundary inside a jitted program.  It is a
+  host callback, and JAX never writes a persistent-cache entry for an
+  executable with host callbacks, so the pipeline builds marked
+  programs only while tracing is enabled.
 
 The listener itself is registered once at import and does work only
 when XLA actually compiles, so the whole module is zero-cost on the
@@ -35,14 +45,19 @@ steady-state hot path.
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
+
+import numpy as np
 
 import jax
+import jax.numpy as jnp
+from jax._src import profiler as _jax_profiler
 
 # the jax.monitoring event XLA emits once per backend compilation; its
 # duration is the device-true compile cost of that one program
@@ -54,8 +69,14 @@ _local = threading.local()          # per-thread active-span stack
 _enabled = False
 _tracing_depth = 0                  # open tracing() sessions, all threads
 _records: List["Span"] = []         # completed spans, append order
-_events: List[Dict[str, Any]] = []  # trace events (only while enabled)
 _MAX_RECORDS = 65536                # hard cap: tracing never grows unbounded
+
+# spans opened with keep=True, always (tracing on or off), newest last
+KEPT_MAX = 1024
+_kept: "deque[Span]" = deque(maxlen=KEPT_MAX)
+
+# (stage, host perf_counter) of every stage mark fired, until taken
+_marks: List[Tuple[str, float]] = []
 
 # cumulative compile counters (always on; fed by the monitoring listener)
 _compile_count = 0
@@ -133,6 +154,12 @@ class Span:
                     **({"attrs": self.attrs} if self.attrs else {}))
 
 
+def _profiler_on() -> bool:
+    """Whether a ``jax.profiler`` session is collecting right now (JAX
+    has no public accessor; ``start_trace`` sets this state)."""
+    return _jax_profiler._profile_state.profile_session is not None
+
+
 def _stack() -> List[Span]:
     st = getattr(_local, "stack", None)
     if st is None:
@@ -141,18 +168,26 @@ def _stack() -> List[Span]:
 
 
 @contextmanager
-def span(name: str, *, fence: bool = False, **attrs):
+def span(name: str, *, fence: bool = False, keep: bool = False, **attrs):
     """Time a region; nestable and thread-safe (each thread keeps its
     own stack).  The span object is yielded so callers can read
-    ``sp.duration`` / ``sp.run_s`` afterwards and ``sp.fence(value)``
-    device outputs at stage boundaries (DESIGN.md §15.1).
+    ``sp.duration`` / ``sp.run_s`` afterwards, ``sp.fence(value)``
+    device outputs at stage boundaries and add ``sp.attrs``
+    (DESIGN.md §15.1).
 
     Spans always measure; they are appended to the global trace buffer
-    only while tracing is :func:`enable`\\ d."""
+    only while tracing is :func:`enable`\\ d, and to the kept ring
+    whenever ``keep`` is set.  While a ``jax.profiler`` session is on,
+    the span is also a ``TraceAnnotation``, so it sits on the device
+    trace's own clock."""
     st = _stack()
     sp = Span(name=name, fenced=fence, depth=len(st),
               parent=st[-1].name if st else None,
               thread=threading.get_ident(), attrs=dict(attrs))
+    ann = None
+    if _profiler_on():
+        ann = jax.profiler.TraceAnnotation(name)
+        ann.__enter__()
     with _lock:
         c0, s0 = _compile_count, _compile_secs
     st.append(sp)
@@ -162,6 +197,8 @@ def span(name: str, *, fence: bool = False, **attrs):
     finally:
         sp.duration = time.perf_counter() - sp.start
         st.pop()
+        if ann is not None:
+            ann.__exit__(None, None, None)
         with _lock:
             # cross-thread compiles can leak into the delta; single-
             # threaded callers (every current caller) see exact counts
@@ -169,6 +206,8 @@ def span(name: str, *, fence: bool = False, **attrs):
             sp.compile_s = _compile_secs - s0
             if (_enabled or _tracing_depth) and len(_records) < _MAX_RECORDS:
                 _records.append(sp)
+            if keep:
+                _kept.append(sp)
 
 
 # ---------------------------------------------------------------------------
@@ -176,7 +215,7 @@ def span(name: str, *, fence: bool = False, **attrs):
 # ---------------------------------------------------------------------------
 
 def enable() -> None:
-    """Start collecting spans/events into the trace buffer."""
+    """Start collecting spans into the trace buffer."""
     global _enabled
     _enabled = True
 
@@ -216,29 +255,71 @@ def spans(name: Optional[str] = None) -> List[Span]:
     return out if name is None else [s for s in out if s.name == name]
 
 
-def events(name: Optional[str] = None) -> List[Dict[str, Any]]:
+def kept_spans(name: Optional[str] = None) -> List[Span]:
+    """Snapshot of the kept ring (spans opened with ``keep=True``, at
+    most :data:`KEPT_MAX`, oldest first), optionally filtered by name.
+    Filled whether or not tracing is on: the fused pipeline keeps one
+    ``pipeline.fused`` record per call (DESIGN.md §15.1)."""
     with _lock:
-        out = list(_events)
-    return out if name is None else [e for e in out if e["name"] == name]
-
-
-def record_event(name: str, **attrs) -> None:
-    """Append an instantaneous event to the trace buffer (collected
-    only while tracing is enabled)."""
-    if not (_enabled or _tracing_depth):
-        return
-    with _lock:
-        if len(_events) < _MAX_RECORDS:
-            _events.append(dict(kind="event", name=name,
-                                t=time.perf_counter(), **attrs))
+        out = list(_kept)
+    return out if name is None else [s for s in out if s.name == name]
 
 
 def clear() -> None:
-    """Drop collected spans/events (compile counters are cumulative;
-    see :func:`watch_recompiles` for windowed readings)."""
+    """Drop collected spans (the kept ring and the compile counters are
+    always-on views; see :func:`watch_recompiles` for windowed
+    readings)."""
     with _lock:
         _records.clear()
-        _events.clear()
+
+
+# ---------------------------------------------------------------------------
+# opt-in stage marks inside a jitted program (§15.5)
+# ---------------------------------------------------------------------------
+
+def _on_mark(stage: str, x) -> np.ndarray:
+    t = time.perf_counter()
+    with _lock:
+        _marks.append((stage, t))
+    return np.full(np.shape(x), -0.0, np.float32)
+
+
+class StageMarks:
+    """Host-clock marks at the stage boundaries of one traced program
+    body (DESIGN.md §15.5); a no-op unless ``on``.
+
+    ``marks(stage, xs)`` marks the end of ``stage`` and returns ``xs``,
+    the values the next stage reads.  The mark's host callback takes one
+    scalar, the sum of the first element of every array of ``xs``, so it
+    fires once all of them are computed and nothing large crosses to the
+    host.  The callback answers ``-0.0``, and every array of ``xs`` comes
+    back with that added (``x + -0.0`` is ``x`` bit for bit; 0 or False
+    for integers and booleans), so the next stage reads data that waits
+    for the mark.  An optimization barrier cannot order them: XLA drops
+    barriers before scheduling.  Under ``vmap`` the callback fires once
+    per stage for the whole batch."""
+
+    def __init__(self, on: bool):
+        self.on = on
+
+    def __call__(self, stage: str, xs):
+        if not self.on:
+            return xs
+        leaves = jax.tree.leaves(xs)
+        s = sum(jnp.ravel(x)[0].astype(jnp.float32) for x in leaves)
+        tok = jax.pure_callback(functools.partial(_on_mark, stage),
+                                jax.ShapeDtypeStruct((), jnp.float32), s,
+                                vmap_method="expand_dims")
+        return jax.tree.map(lambda x: x + tok.astype(x.dtype), xs)
+
+
+def take_marks() -> List[Tuple[str, float]]:
+    """The ``(stage, perf_counter)`` marks fired since the last call,
+    oldest first; clears them."""
+    with _lock:
+        out = list(_marks)
+        _marks.clear()
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -320,7 +401,6 @@ def record_recompile(detail: str = "", **attrs) -> None:
         _recompile_log.append(dict(kind="event", name="recompile",
                                    t=time.perf_counter(), detail=detail,
                                    **attrs))
-    record_event("recompile", detail=detail, **attrs)
 
 
 def recompile_events() -> List[Dict[str, Any]]:

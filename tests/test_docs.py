@@ -168,7 +168,7 @@ def test_readme_and_api_document_obs():
     api = (ROOT / "docs" / "api.md").read_text()
     assert "repro.obs" in api
     for name in ("watch_recompiles", "compile_s", "snapshot",
-                 "healthz", "dump_jsonl"):
+                 "healthz"):
         assert name in api, f"docs/api.md lost {name}"
     bench = (ROOT / "docs" / "benchmarks.md").read_text()
     assert "--check-schema" in bench and "replay_recompiles" in bench

@@ -342,30 +342,6 @@ def test_service_healthz_contract():
 
 
 # ---------------------------------------------------------------------------
-# export (§15.4)
-# ---------------------------------------------------------------------------
-
-def test_dump_jsonl_round_trips(tmp_path):
-    import json
-
-    obs_trace.clear()
-    with obs_trace.tracing():
-        with obs_trace.span("dumped", fence=False):
-            obs_trace.record_event("marker", detail="x")
-    path = tmp_path / "trace.jsonl"
-    n = obs_export.dump_jsonl(str(path))
-    lines = [json.loads(l) for l in path.read_text().splitlines()]
-    assert len(lines) == n >= 3                  # span + event + metrics
-    kinds = {l["kind"] for l in lines}
-    assert {"span", "event", "metrics"} <= kinds
-    sp = [l for l in lines if l["kind"] == "span"
-          and l["name"] == "dumped"][0]
-    assert set(sp) >= {"duration", "compiles", "compile_s", "run_s"}
-    metrics_line = [l for l in lines if l["kind"] == "metrics"][0]
-    assert "programs" in metrics_line["compile"]
-
-
-# ---------------------------------------------------------------------------
 # coverage gaps (ISSUE 8 satellite): concurrent tracing, watch nesting,
 # render edge cases
 # ---------------------------------------------------------------------------
