@@ -42,7 +42,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.tmfg import NEG, TMFGResult, _State
+from repro.core.tmfg import (NEG, TMFGResult, _State, _clique_edges,
+                             _clique_faces, _face, _insert_one, _result,
+                             _root_state, _split_face)
 from repro.kernels.ref import SIM_PRECISION
 
 from .knn import TopKTable
@@ -194,36 +196,17 @@ def _init_sparse(topv, topi, src, from_x: bool, n: int, bm: int
                  ) -> _SparseState:
     """Mirror of ``tmfg._init_state`` driven by the table: identical
     clique choice, edge bookkeeping and face gains at full K."""
-    F, E, B = 2 * n - 4, 3 * n - 6, n - 3
+    F, E = 2 * n - 4, 3 * n - 6
     row_sums = _row_sums_blocked(topv, topi, n, bm)
     _, idx = lax.top_k(row_sums, 4)
     clique = jnp.sort(idx).astype(jnp.int32)
-    v1, v2, v3, v4 = clique[0], clique[1], clique[2], clique[3]
-
     inserted = jnp.zeros((n,), bool).at[clique].set(True)
-    insert_order = jnp.zeros((n,), jnp.int32).at[:4].set(clique)
 
-    pair = lambda x, y: jnp.stack([x, y])
-    edges = jnp.zeros((E, 2), jnp.int32)
-    init_edges = jnp.stack([pair(v1, v2), pair(v1, v3), pair(v1, v4),
-                            pair(v2, v3), pair(v2, v4), pair(v3, v4)])
-    edges = edges.at[:6].set(init_edges.astype(jnp.int32))
+    init_edges = _clique_edges(clique)
     pv = functools.partial(_pair_value, src, from_x, topv, topi)
     w6, hits6 = jax.vmap(pv)(init_edges[:, 0], init_edges[:, 1])
-    edge_sum = w6.sum()
     w_edges = jnp.zeros((E,), jnp.float32).at[:6].set(w6)
-
-    tri = lambda x, y, z: jnp.stack([x, y, z])
-    faces = jnp.zeros((F, 3), jnp.int32)
-    init_faces = jnp.stack([tri(v1, v2, v3), tri(v1, v2, v4),
-                            tri(v1, v3, v4), tri(v2, v3, v4)])
-    faces = faces.at[:4].set(init_faces.astype(jnp.int32))
-    face_bubble = jnp.zeros((F,), jnp.int32)
-
-    bubble_verts = jnp.zeros((B, 4), jnp.int32).at[0].set(clique)
-    bubble_parent = jnp.full((B,), -1, jnp.int32)
-    bubble_tri = jnp.full((B, 3), -1, jnp.int32)
-    home_bubble = jnp.zeros((n,), jnp.int32)
+    faces = _clique_faces(clique, F)
 
     maxcorr = _maxcorr_blocked(topv, topi, inserted, n, bm)
 
@@ -236,14 +219,8 @@ def _init_sparse(topv, topi, src, from_x: bool, n: int, bm: int
     gains = jnp.take_along_axis(g, j[:, None], axis=1)[:, 0]
     gains = jnp.where(valid, gains, NEG)
 
-    st = _State(
-        inserted=inserted, n_inserted=jnp.int32(4), maxcorr=maxcorr,
-        gains=gains, best_v=best_v, faces=faces, face_bubble=face_bubble,
-        n_faces=jnp.int32(4), edges=edges, n_edges=jnp.int32(6),
-        edge_sum=edge_sum, insert_order=insert_order,
-        bubble_verts=bubble_verts, bubble_parent=bubble_parent,
-        bubble_tri=bubble_tri, home_bubble=home_bubble, pops=jnp.int32(0),
-    )
+    st = _root_state(clique, n, edge_sum=w6.sum(), maxcorr=maxcorr,
+                     best_v=best_v, gains=gains)
     init_pairs = 6 + 9 * 4                                  # clique + faces
     init_miss = (6 - hits6.sum()) + jnp.sum(
         jnp.where(valid[:, None, None], ~hits, False))
@@ -284,7 +261,7 @@ def sparse_lazy_tmfg(topv: jax.Array, topi: jax.Array, src: jax.Array,
 
     def refresh(s: _SparseState, f):
         st = s.st
-        face = st.faces[f]
+        face = _face(st, f)
         mc, fb = st.maxcorr, jnp.int32(0)
         for i in range(3):
             v, fell = lookup(st.inserted, face[i])
@@ -300,15 +277,16 @@ def sparse_lazy_tmfg(topv: jax.Array, topi: jax.Array, src: jax.Array,
 
     def do_insert(s: _SparseState, f, v):
         st = s.st
-        face = st.faces[f]
+        face = _face(st, f)
         a, b, c = face[0], face[1], face[2]
         slots = jnp.stack([f, st.n_faces, st.n_faces + 1])
         # the three new edge weights, dense orientation S[v, ·]
         wv, hv = jax.vmap(pairval, in_axes=(None, 0))(
             v, jnp.stack([a, b, c]))
-        st = _insert_one_sparse(st, f, v, wv)
+        # insertion i (from 0) adds edge rows 6 + 3i .. 8 + 3i
         w_edges = lax.dynamic_update_slice(
-            s.w_edges, wv, (st.n_edges - 3,))
+            s.w_edges, wv, (3 * st.n_inserted - 6,))
+        st = _insert_one(st, f, face, v, wv)
         # refresh maxcorr for the 4 clique vertices (Alg. 2 lines 21-22)
         mc, fb = st.maxcorr, jnp.int32(0)
         for w in (v, a, b, c):
@@ -317,8 +295,9 @@ def sparse_lazy_tmfg(topv: jax.Array, topi: jax.Array, src: jax.Array,
             fb = fb + fell
         # pairs for the 3 new face slots (Alg. 2 lines 23-25)
         best_v, gains, miss = st.best_v, st.gains, jnp.int32(0)
+        new_faces = _split_face(face, v)
         for i in range(3):
-            bv, g, m = face_pair(mc, st.faces[slots[i]])
+            bv, g, m = face_pair(mc, new_faces[3 * i:3 * i + 3])
             best_v = best_v.at[slots[i]].set(bv)
             gains = gains.at[slots[i]].set(g)
             miss = miss + m
@@ -342,57 +321,11 @@ def sparse_lazy_tmfg(topv: jax.Array, topi: jax.Array, src: jax.Array,
     s0 = _init_sparse(topv, topi, src, from_x, n, bm)
     s = lax.while_loop(lambda q: q.st.n_inserted < n, body, s0)
 
-    st = s.st
-    result = TMFGResult(
-        clique=st.insert_order[:4], edges=st.edges, faces=st.faces,
-        insert_order=st.insert_order, bubble_verts=st.bubble_verts,
-        bubble_parent=st.bubble_parent, bubble_tri=st.bubble_tri,
-        home_bubble=st.home_bubble, edge_sum=st.edge_sum, pops=st.pops)
+    result = _result(s.st)
     counters = SparseCounters(
         lookups=s.lookups, fallbacks=s.fallbacks,
         pair_lookups=s.pair_lookups, pair_misses=s.pair_misses)
     return result, s.w_edges, counters
-
-
-def _insert_one_sparse(st: _State, f, v, wv) -> _State:
-    """``tmfg._insert_one`` with the three edge values supplied
-    (``wv = [S[v,a], S[v,b], S[v,c]]``) instead of gathered from S —
-    same scatters, same left-fold edge_sum accumulation."""
-    face = st.faces[f]
-    a, b, c = face[0], face[1], face[2]
-    inserted = st.inserted.at[v].set(True)
-    n_before = st.n_inserted
-    insert_order = st.insert_order.at[n_before].set(v)
-    n_inserted = n_before + 1
-
-    new_edges = jnp.stack(
-        [jnp.stack([v, a]), jnp.stack([v, b]), jnp.stack([v, c])]
-    ).astype(jnp.int32)
-    edges = lax.dynamic_update_slice(st.edges, new_edges, (st.n_edges, 0))
-    edge_sum = st.edge_sum + wv[0] + wv[1] + wv[2]
-
-    bub = n_inserted - 4
-    bubble_verts = st.bubble_verts.at[bub].set(
-        jnp.stack([v, a, b, c]).astype(jnp.int32))
-    bubble_parent = st.bubble_parent.at[bub].set(st.face_bubble[f])
-    bubble_tri = st.bubble_tri.at[bub].set(face)
-    home_bubble = st.home_bubble.at[v].set(bub)
-
-    faces = st.faces.at[f].set(jnp.stack([v, a, b]).astype(jnp.int32))
-    faces = faces.at[st.n_faces].set(jnp.stack([v, b, c]).astype(jnp.int32))
-    faces = faces.at[st.n_faces + 1].set(
-        jnp.stack([v, a, c]).astype(jnp.int32))
-    face_bubble = st.face_bubble.at[f].set(bub)
-    face_bubble = face_bubble.at[st.n_faces].set(bub)
-    face_bubble = face_bubble.at[st.n_faces + 1].set(bub)
-
-    return st._replace(
-        inserted=inserted, n_inserted=n_inserted, faces=faces,
-        face_bubble=face_bubble, n_faces=st.n_faces + 2, edges=edges,
-        n_edges=st.n_edges + 3, edge_sum=edge_sum, insert_order=insert_order,
-        bubble_verts=bubble_verts, bubble_parent=bubble_parent,
-        bubble_tri=bubble_tri, home_bubble=home_bubble,
-    )
 
 
 @functools.partial(jax.jit, static_argnames=("from_x", "bm"))

@@ -36,7 +36,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.dist import sharding as dist_sh
 from . import config as config_mod
 from .config import PipelineConfig
-from .tmfg import TMFGResult, _State, _face_pair, _init_state, _insert_one
+from .tmfg import (TMFGResult, _clique_edges, _clique_faces, _face,
+                   _insert_one, _result, _root_state, _split_face)
 
 NEG = -jnp.inf
 
@@ -187,7 +188,7 @@ def build_tmfg_sharded(S: jax.Array, mesh: Mesh, *, axis="data",
             st = _lazy_loop_sharded_batched(st, lookup_many, gather_many, n)
         else:
             st = _lazy_loop_sharded(st, lookup, gather, n)
-        return _result_of(st)
+        return _result(st)
 
     out = jax.shard_map(
         fn, mesh=mesh, in_specs=dist_sh.timeseries_spec(axis),
@@ -240,25 +241,15 @@ def _init_maxcorr_all(Sl, n_local, axis, inserted, n):
 
 def _init_sharded(row_sums, lookup, gather, n, maxcorr_all=None):
     """Replicated-state init mirroring tmfg._init_state but with sharded S."""
-    F, E, B = 2 * n - 4, 3 * n - 6, n - 3
+    F = 2 * n - 4
     _, idx = lax.top_k(row_sums, 4)
     clique = jnp.sort(idx).astype(jnp.int32)
-    v1, v2, v3, v4 = clique[0], clique[1], clique[2], clique[3]
-
     inserted = jnp.zeros((n,), bool).at[clique].set(True)
-    insert_order = jnp.zeros((n,), jnp.int32).at[:4].set(clique)
 
-    pair = lambda x, y: jnp.stack([x, y])
-    init_edges = jnp.stack([pair(v1, v2), pair(v1, v3), pair(v1, v4),
-                            pair(v2, v3), pair(v2, v4), pair(v3, v4)])
-    edges = jnp.zeros((E, 2), jnp.int32).at[:6].set(init_edges.astype(jnp.int32))
+    init_edges = _clique_edges(clique)
     edge_sum = sum(gather(init_edges[i, 0], init_edges[i, 1])
                    for i in range(6))
-
-    tri = lambda x, y, z: jnp.stack([x, y, z])
-    init_faces = jnp.stack([tri(v1, v2, v3), tri(v1, v2, v4),
-                            tri(v1, v3, v4), tri(v2, v3, v4)])
-    faces = jnp.zeros((F, 3), jnp.int32).at[:4].set(init_faces.astype(jnp.int32))
+    faces = _clique_faces(clique, F)
 
     if maxcorr_all is not None:
         maxcorr = maxcorr_all(inserted)
@@ -274,62 +265,15 @@ def _init_sharded(row_sums, lookup, gather, n, maxcorr_all=None):
         best_v = best_v.at[i].set(bv)
         gains = gains.at[i].set(g)
 
-    return _State(
-        inserted=inserted, n_inserted=jnp.int32(4), maxcorr=maxcorr,
-        gains=gains, best_v=best_v, faces=faces,
-        face_bubble=jnp.zeros((F,), jnp.int32), n_faces=jnp.int32(4),
-        edges=edges, n_edges=jnp.int32(6),
-        edge_sum=edge_sum.astype(jnp.float32), insert_order=insert_order,
-        bubble_verts=jnp.zeros((B, 4), jnp.int32).at[0].set(clique),
-        bubble_parent=jnp.full((B,), -1, jnp.int32),
-        bubble_tri=jnp.full((B, 3), -1, jnp.int32),
-        home_bubble=jnp.zeros((n,), jnp.int32), pops=jnp.int32(0),
-    )
+    return _root_state(clique, n, edge_sum=edge_sum.astype(jnp.float32),
+                       maxcorr=maxcorr, best_v=best_v, gains=gains)
 
 
 def _lazy_loop_sharded(st, lookup, gather, n):
     """The LAZY pop loop with sharded lookups (state replicated)."""
 
-    def insert_bookkeeping(st, f, v):
-        # _insert_one needs S only for the edge-sum update; recompute that
-        # term with the sharded gather and patch it.
-        face = st.faces[f]
-        es_inc = _gain_of(gather, face, v)
-        fake_S = jnp.zeros((1, 1), jnp.float32)  # placeholder, not indexed
-
-        # replicate _insert_one's bookkeeping inline (S-free):
-        a, b, c = face[0], face[1], face[2]
-        inserted = st.inserted.at[v].set(True)
-        n_before = st.n_inserted
-        insert_order = st.insert_order.at[n_before].set(v)
-        n_inserted = n_before + 1
-        new_edges = jnp.stack([jnp.stack([v, a]), jnp.stack([v, b]),
-                               jnp.stack([v, c])]).astype(jnp.int32)
-        edges = lax.dynamic_update_slice(st.edges, new_edges, (st.n_edges, 0))
-        bub = n_inserted - 4
-        bubble_verts = st.bubble_verts.at[bub].set(
-            jnp.stack([v, a, b, c]).astype(jnp.int32))
-        bubble_parent = st.bubble_parent.at[bub].set(st.face_bubble[f])
-        bubble_tri = st.bubble_tri.at[bub].set(face)
-        home_bubble = st.home_bubble.at[v].set(bub)
-        faces = st.faces.at[f].set(jnp.stack([v, a, b]).astype(jnp.int32))
-        faces = faces.at[st.n_faces].set(jnp.stack([v, b, c]).astype(jnp.int32))
-        faces = faces.at[st.n_faces + 1].set(
-            jnp.stack([v, a, c]).astype(jnp.int32))
-        face_bubble = st.face_bubble.at[f].set(bub)
-        face_bubble = face_bubble.at[st.n_faces].set(bub)
-        face_bubble = face_bubble.at[st.n_faces + 1].set(bub)
-        return st._replace(
-            inserted=inserted, n_inserted=n_inserted, faces=faces,
-            face_bubble=face_bubble, n_faces=st.n_faces + 2, edges=edges,
-            n_edges=st.n_edges + 3, edge_sum=st.edge_sum + es_inc,
-            insert_order=insert_order, bubble_verts=bubble_verts,
-            bubble_parent=bubble_parent, bubble_tri=bubble_tri,
-            home_bubble=home_bubble,
-        ), face
-
     def refresh(st, f):
-        face = st.faces[f]
+        face = _face(st, f)
         mc = st.maxcorr
         for i in range(3):
             mc = mc.at[face[i]].set(lookup(st.inserted, face[i]))
@@ -339,13 +283,17 @@ def _lazy_loop_sharded(st, lookup, gather, n):
 
     def do_insert(st, f, v):
         slots = jnp.stack([f, st.n_faces, st.n_faces + 1])
-        st, face = insert_bookkeeping(st, f, v)
+        face = _face(st, f)
+        st = _insert_one(st, f, face, v,
+                         jnp.stack([gather(face[i], v) for i in range(3)]))
         mc = st.maxcorr
         for w in (v, face[0], face[1], face[2]):
             mc = mc.at[w].set(lookup(st.inserted, w))
         best_v, gains = st.best_v, st.gains
+        new_faces = _split_face(face, v)
         for i in range(3):
-            bv, g = _face_pair_sharded(gather, mc, st.faces[slots[i]])
+            bv, g = _face_pair_sharded(gather, mc,
+                                       new_faces[3 * i:3 * i + 3])
             best_v = best_v.at[slots[i]].set(bv)
             gains = gains.at[slots[i]].set(g)
         return st._replace(maxcorr=mc, best_v=best_v, gains=gains)
@@ -380,7 +328,7 @@ def _lazy_loop_sharded_batched(st, lookup_many, gather_many, n):
         return cands, vals
 
     def refresh(st, f):
-        face = st.faces[f]
+        face = _face(st, f)
         mc = st.maxcorr.at[face].set(lookup_many(st.inserted, face))
         cands = mc[face]                                    # (3,)
         rs = jnp.broadcast_to(face[None, :], (3, 3)).reshape(-1)
@@ -393,46 +341,17 @@ def _lazy_loop_sharded_batched(st, lookup_many, gather_many, n):
             gains=st.gains.at[f].set(g[j]))
 
     def do_insert(st, f, v):
-        face = st.faces[f]
+        face = _face(st, f)
         a, b, c = face[0], face[1], face[2]
-        es_inc = gather_many(face, jnp.stack([v, v, v])).sum()
         slots = jnp.stack([f, st.n_faces, st.n_faces + 1])
-
-        inserted = st.inserted.at[v].set(True)
-        n_before = st.n_inserted
-        insert_order = st.insert_order.at[n_before].set(v)
-        n_inserted = n_before + 1
-        new_edges = jnp.stack([jnp.stack([v, a]), jnp.stack([v, b]),
-                               jnp.stack([v, c])]).astype(jnp.int32)
-        edges = lax.dynamic_update_slice(st.edges, new_edges,
-                                         (st.n_edges, 0))
-        bub = n_inserted - 4
-        bubble_verts = st.bubble_verts.at[bub].set(
-            jnp.stack([v, a, b, c]).astype(jnp.int32))
-        bubble_parent = st.bubble_parent.at[bub].set(st.face_bubble[f])
-        bubble_tri = st.bubble_tri.at[bub].set(face)
-        home_bubble = st.home_bubble.at[v].set(bub)
-        faces = st.faces.at[f].set(jnp.stack([v, a, b]).astype(jnp.int32))
-        faces = faces.at[st.n_faces].set(
-            jnp.stack([v, b, c]).astype(jnp.int32))
-        faces = faces.at[st.n_faces + 1].set(
-            jnp.stack([v, a, c]).astype(jnp.int32))
-        face_bubble = st.face_bubble.at[f].set(bub)
-        face_bubble = face_bubble.at[st.n_faces].set(bub)
-        face_bubble = face_bubble.at[st.n_faces + 1].set(bub)
-        st = st._replace(
-            inserted=inserted, n_inserted=n_inserted, faces=faces,
-            face_bubble=face_bubble, n_faces=st.n_faces + 2, edges=edges,
-            n_edges=st.n_edges + 3, edge_sum=st.edge_sum + es_inc,
-            insert_order=insert_order, bubble_verts=bubble_verts,
-            bubble_parent=bubble_parent, bubble_tri=bubble_tri,
-            home_bubble=home_bubble)
+        st = _insert_one(st, f, face, v,
+                         gather_many(face, jnp.stack([v, v, v])))
 
         # ONE all-gather: MaxCorrs for the new 4-clique
         four = jnp.stack([v, a, b, c])
         mc = st.maxcorr.at[four].set(lookup_many(st.inserted, four))
         # ONE psum: gains of the 3 new faces' candidates
-        faces3 = st.faces[slots]                            # (3, 3)
+        faces3 = _split_face(face, v).reshape(3, 3)
         cands, g = face_gains(mc, faces3)
         j = jnp.argmax(g, axis=1)
         best3 = cands[jnp.arange(3), j].astype(jnp.int32)
@@ -450,15 +369,6 @@ def _lazy_loop_sharded_batched(st, lookup_many, gather_many, n):
         return st._replace(pops=st.pops + 1)
 
     return lax.while_loop(lambda s: s.n_inserted < n, body, st)
-
-
-def _result_of(st) -> TMFGResult:
-    return TMFGResult(
-        clique=st.insert_order[:4], edges=st.edges, faces=st.faces,
-        insert_order=st.insert_order, bubble_verts=st.bubble_verts,
-        bubble_parent=st.bubble_parent, bubble_tri=st.bubble_tri,
-        home_bubble=st.home_bubble, edge_sum=st.edge_sum, pops=st.pops,
-    )
 
 
 # ---------------------------------------------------------------------------

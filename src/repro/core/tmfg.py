@@ -27,6 +27,12 @@ Hardware adaptation notes (see DESIGN.md §2):
     the paper's AVX-vectorized "advance past inserted vertices" scan.
   * All state is fixed-shape so the entire construction jit-compiles into a
     single ``lax.while_loop`` / ``lax.fori_loop`` program.
+  * A hot loop's carry passes no table through a ``lax.cond``: the branch
+    that leaves it unchanged would copy it on every iteration, and on a TPU
+    an ``(N, k)`` int table with k ≤ 4 pads to 128 lanes.  The lazy pop
+    writes the insertion's bookkeeping in place on every pop, gated off on
+    a stale one, and keeps its tables flat; batched, it loops on one scalar
+    predicate instead of selecting the whole carry after every pop.
 """
 
 from __future__ import annotations
@@ -36,7 +42,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import custom_batching, lax
 
 NEG = -jnp.inf
 
@@ -57,21 +63,26 @@ class TMFGResult(NamedTuple):
 
 
 class _State(NamedTuple):
+    """The construction's carried state.  The two tables are kept flat,
+    row after row (face slot s is ``faces[3s:3s+3]``): an ``(N, k)`` int
+    table with k ≤ 4 pads its minor dimension to 128 lanes on a TPU, a flat
+    one is dense.  The edges and the bubbles' vertices are not carried:
+    insertion i adds the edges from its vertex to the corners of the face
+    it split, ``bubble_tri``'s row i, and :func:`_result` builds both
+    tables from the two once, after the loop."""
+
     inserted: jax.Array       # (n,) bool
     n_inserted: jax.Array     # () i32
     maxcorr: jax.Array        # (n,) i32 — cached best uninserted vertex per row
     gains: jax.Array          # (F,) f32 — cached gain per face slot
     best_v: jax.Array         # (F,) i32 — cached best vertex per face slot
-    faces: jax.Array          # (F, 3) i32
+    faces: jax.Array          # (3F,) i32 — F faces of 3 corners
     face_bubble: jax.Array    # (F,) i32
     n_faces: jax.Array        # () i32
-    edges: jax.Array          # (E, 2) i32
-    n_edges: jax.Array        # () i32
     edge_sum: jax.Array       # () f32
     insert_order: jax.Array   # (n,) i32
-    bubble_verts: jax.Array   # (B, 4) i32
     bubble_parent: jax.Array  # (B,) i32
-    bubble_tri: jax.Array     # (B, 3) i32
+    bubble_tri: jax.Array     # (3B,) i32 — B separating triangles
     home_bubble: jax.Array    # (n,) i32
     pops: jax.Array           # () i32
 
@@ -136,45 +147,124 @@ def _all_face_pairs(S, maxcorr, faces, valid_mask):
 
 
 # ---------------------------------------------------------------------------
-# shared single-insertion routine
+# shared state: the root clique, one insertion, the result
 # ---------------------------------------------------------------------------
 
-def _insert_one(S: jax.Array, st: _State, f: jax.Array, v: jax.Array) -> _State:
-    """Insert vertex v into face slot f.  Pure bookkeeping, O(1) scatters."""
-    face = st.faces[f]
+def _clique_edges(clique: jax.Array) -> jax.Array:
+    """The root clique's 6 edges, (6, 2)."""
+    v1, v2, v3, v4 = clique[0], clique[1], clique[2], clique[3]
+    pair = lambda x, y: jnp.stack([x, y])
+    return jnp.stack([pair(v1, v2), pair(v1, v3), pair(v1, v4),
+                      pair(v2, v3), pair(v2, v4), pair(v3, v4)]
+                     ).astype(jnp.int32)
+
+
+def _clique_faces(clique: jax.Array, F: int) -> jax.Array:
+    """The (F, 3) face table holding the root clique's 4 faces."""
+    v1, v2, v3, v4 = clique[0], clique[1], clique[2], clique[3]
+    tri = lambda x, y, z: jnp.stack([x, y, z])
+    init = jnp.stack([tri(v1, v2, v3), tri(v1, v2, v4),
+                      tri(v1, v3, v4), tri(v2, v3, v4)])
+    return jnp.zeros((F, 3), jnp.int32).at[:4].set(init.astype(jnp.int32))
+
+
+def _root_state(clique: jax.Array, n: int, *, edge_sum, maxcorr, best_v,
+                gains) -> _State:
+    """The state at the root clique (4 vertices, 6 edges, 4 faces, bubble
+    0) around the caches and edge sum that each construction computes its
+    own way."""
+    F, B = 2 * n - 4, n - 3
+    return _State(
+        inserted=jnp.zeros((n,), bool).at[clique].set(True),
+        n_inserted=jnp.int32(4), maxcorr=maxcorr, gains=gains,
+        best_v=best_v, faces=_clique_faces(clique, F).reshape(-1),
+        face_bubble=jnp.zeros((F,), jnp.int32), n_faces=jnp.int32(4),
+        edge_sum=edge_sum,
+        insert_order=jnp.zeros((n,), jnp.int32).at[:4].set(clique),
+        bubble_parent=jnp.full((B,), -1, jnp.int32),
+        bubble_tri=jnp.full((3 * B,), -1, jnp.int32),
+        home_bubble=jnp.zeros((n,), jnp.int32), pops=jnp.int32(0),
+    )
+
+
+def _face(st: _State, f: jax.Array) -> jax.Array:
+    """Face slot f's three corners."""
+    return lax.dynamic_slice(st.faces, (3 * f,), (3,))
+
+
+def _put(table, idx, new, write):
+    """Write ``new`` into the flat ``table`` at positions ``idx``, in place;
+    with ``write`` False the indices go out of range and the write drops."""
+    return table.at[jnp.where(write, idx, table.shape[0])].set(new,
+                                                               mode="drop")
+
+
+def _span(start, k):
+    """The k positions from ``start`` on."""
+    return start + jnp.arange(k)
+
+
+def _split_face(face: jax.Array, v: jax.Array) -> jax.Array:
+    """The faces that inserting v into ``face`` = (a,b,c) makes, flat:
+    (v,a,b) takes the face's slot, (v,b,c) and (v,a,c) are appended."""
     a, b, c = face[0], face[1], face[2]
-    inserted = st.inserted.at[v].set(True)
+    return jnp.stack([v, a, b, v, b, c, v, a, c]).astype(jnp.int32)
+
+
+def _insert_one(st: _State, f: jax.Array, face: jax.Array, v: jax.Array,
+                w: jax.Array, write=True) -> _State:
+    """Insert vertex v into face slot f.  Pure bookkeeping, O(1) writes.
+
+    ``face`` is slot f's corners (a, b, c) and ``w`` the new edges'
+    similarities (S[v,a], S[v,b], S[v,c]), which the edge sum adds in that
+    order; each construction finds them its own way.  Every write lands in
+    place, one scatter a table.  A traced ``write`` that is False makes the
+    call a no-op: each write drops, and the counts and the edge sum keep
+    their values.  The lazy loop runs it so on every pop, and no carried
+    table passes through a branch (DESIGN.md §2)."""
+    def count(k):
+        return jnp.where(write, k, 0)
+
     n_before = st.n_inserted
-    insert_order = st.insert_order.at[n_before].set(v)
-    n_inserted = n_before + 1
-
-    new_edges = jnp.stack(
-        [jnp.stack([v, a]), jnp.stack([v, b]), jnp.stack([v, c])]
-    ).astype(jnp.int32)
-    edges = lax.dynamic_update_slice(st.edges, new_edges, (st.n_edges, 0))
-    edge_sum = st.edge_sum + S[v, a] + S[v, b] + S[v, c]
-
-    bub = n_inserted - 4  # bubble ids: 0 = root clique, then one per insert
-    bubble_verts = st.bubble_verts.at[bub].set(
-        jnp.stack([v, a, b, c]).astype(jnp.int32))
-    bubble_parent = st.bubble_parent.at[bub].set(st.face_bubble[f])
-    bubble_tri = st.bubble_tri.at[bub].set(face)
-    home_bubble = st.home_bubble.at[v].set(bub)
-
-    # face slot f is overwritten with (v,a,b); (v,b,c) and (v,a,c) appended.
-    faces = st.faces.at[f].set(jnp.stack([v, a, b]).astype(jnp.int32))
-    faces = faces.at[st.n_faces].set(jnp.stack([v, b, c]).astype(jnp.int32))
-    faces = faces.at[st.n_faces + 1].set(jnp.stack([v, a, c]).astype(jnp.int32))
-    face_bubble = st.face_bubble.at[f].set(bub)
-    face_bubble = face_bubble.at[st.n_faces].set(bub)
-    face_bubble = face_bubble.at[st.n_faces + 1].set(bub)
-
+    bub = n_before - 3  # bubble ids: 0 = root clique, then one per insert
+    new_faces = _split_face(face, v)
+    one = lambda x: jnp.reshape(x, (1,)).astype(jnp.int32)
     return st._replace(
-        inserted=inserted, n_inserted=n_inserted, faces=faces,
-        face_bubble=face_bubble, n_faces=st.n_faces + 2, edges=edges,
-        n_edges=st.n_edges + 3, edge_sum=edge_sum, insert_order=insert_order,
-        bubble_verts=bubble_verts, bubble_parent=bubble_parent,
-        bubble_tri=bubble_tri, home_bubble=home_bubble,
+        inserted=_put(st.inserted, one(v), jnp.ones((1,), bool), write),
+        n_inserted=n_before + count(1),
+        insert_order=_put(st.insert_order, one(n_before), one(v), write),
+        edge_sum=jnp.where(write, st.edge_sum + w[0] + w[1] + w[2],
+                           st.edge_sum),
+        bubble_parent=_put(st.bubble_parent, one(bub),
+                           one(st.face_bubble[f]), write),
+        bubble_tri=_put(st.bubble_tri, _span(3 * bub, 3),
+                        face.astype(jnp.int32), write),
+        home_bubble=_put(st.home_bubble, one(v), one(bub), write),
+        faces=_put(st.faces, jnp.concatenate([_span(3 * f, 3),
+                                              _span(3 * st.n_faces, 6)]),
+                   new_faces, write),
+        face_bubble=_put(st.face_bubble, jnp.stack([f, st.n_faces,
+                                                    st.n_faces + 1]),
+                         jnp.full((3,), bub, jnp.int32), write),
+        n_faces=st.n_faces + count(2),
+    )
+
+
+def _result(st: _State) -> TMFGResult:
+    """The finished build's result; the edges and bubble vertices come
+    from the insert order and the separating triangles."""
+    clique, v = st.insert_order[:4], st.insert_order[4:]
+    tri = st.bubble_tri.reshape(-1, 3)
+    new_edges = jnp.stack([jnp.broadcast_to(v[:, None], tri[1:].shape),
+                           tri[1:]], axis=-1).reshape(-1, 2)
+    return TMFGResult(
+        clique=clique,
+        edges=jnp.concatenate([_clique_edges(clique), new_edges]),
+        faces=st.faces.reshape(-1, 3), insert_order=st.insert_order,
+        bubble_verts=jnp.concatenate(
+            [clique[None], jnp.concatenate([v[:, None], tri[1:]], axis=1)]),
+        bubble_parent=st.bubble_parent, bubble_tri=tri,
+        home_bubble=st.home_bubble, edge_sum=st.edge_sum, pops=st.pops,
     )
 
 
@@ -183,33 +273,12 @@ def _insert_one(S: jax.Array, st: _State, f: jax.Array, v: jax.Array) -> _State:
 # ---------------------------------------------------------------------------
 
 def _init_state(S: jax.Array, n: int) -> _State:
-    F, E, B = 2 * n - 4, 3 * n - 6, n - 3
+    F = 2 * n - 4
     row_sums = jnp.where(jnp.isfinite(S), S, 0.0).sum(axis=1)
     _, idx = lax.top_k(row_sums, 4)
     clique = jnp.sort(idx).astype(jnp.int32)
-    v1, v2, v3, v4 = clique[0], clique[1], clique[2], clique[3]
-
+    init_edges = _clique_edges(clique)
     inserted = jnp.zeros((n,), bool).at[clique].set(True)
-    insert_order = jnp.zeros((n,), jnp.int32).at[:4].set(clique)
-
-    pair = lambda x, y: jnp.stack([x, y])
-    edges = jnp.zeros((E, 2), jnp.int32)
-    init_edges = jnp.stack([pair(v1, v2), pair(v1, v3), pair(v1, v4),
-                            pair(v2, v3), pair(v2, v4), pair(v3, v4)])
-    edges = edges.at[:6].set(init_edges.astype(jnp.int32))
-    edge_sum = S[init_edges[:, 0], init_edges[:, 1]].sum()
-
-    tri = lambda x, y, z: jnp.stack([x, y, z])
-    faces = jnp.zeros((F, 3), jnp.int32)
-    init_faces = jnp.stack([tri(v1, v2, v3), tri(v1, v2, v4),
-                            tri(v1, v3, v4), tri(v2, v3, v4)])
-    faces = faces.at[:4].set(init_faces.astype(jnp.int32))
-    face_bubble = jnp.zeros((F,), jnp.int32)
-
-    bubble_verts = jnp.zeros((B, 4), jnp.int32).at[0].set(clique)
-    bubble_parent = jnp.full((B,), -1, jnp.int32)
-    bubble_tri = jnp.full((B, 3), -1, jnp.int32)
-    home_bubble = jnp.zeros((n,), jnp.int32)
 
     # fresh maxcorr for every row (one batched masked argmax — the "single
     # aggregated parallel step")
@@ -217,62 +286,91 @@ def _init_state(S: jax.Array, n: int) -> _State:
     maxcorr = maxcorr.astype(jnp.int32)
 
     valid = jnp.arange(F) < 4
-    best_v, gains = _all_face_pairs(S, maxcorr, faces, valid)
-
-    return _State(
-        inserted=inserted, n_inserted=jnp.int32(4), maxcorr=maxcorr,
-        gains=gains, best_v=best_v, faces=faces, face_bubble=face_bubble,
-        n_faces=jnp.int32(4), edges=edges, n_edges=jnp.int32(6),
-        edge_sum=edge_sum, insert_order=insert_order,
-        bubble_verts=bubble_verts, bubble_parent=bubble_parent,
-        bubble_tri=bubble_tri, home_bubble=home_bubble, pops=jnp.int32(0),
-    )
+    best_v, gains = _all_face_pairs(S, maxcorr, _clique_faces(clique, F),
+                                    valid)
+    return _root_state(
+        clique, n, edge_sum=S[init_edges[:, 0], init_edges[:, 1]].sum(),
+        maxcorr=maxcorr, best_v=best_v, gains=gains)
 
 
 # ---------------------------------------------------------------------------
 # LAZY (heap-equivalent) construction — the paper's HEAP-TMFG
 # ---------------------------------------------------------------------------
 
-def _build_lazy(S: jax.Array, n: int, lookup) -> _State:
-    def refresh(st: _State, f):
+def _lazy_pop(S: jax.Array, topk_idx: Optional[jax.Array],
+              st: _State) -> _State:
+    """One pop of the lazy loop (Alg. 2's loop body).
+
+    The insertion's bookkeeping runs on every pop, written in place and
+    gated off on a stale one.  Only the refresh of ``maxcorr`` and the
+    face slots' ``(best_v, gains)`` differs between the two cases, so only
+    those three 1-D arrays pass through the ``lax.cond``.  A finished build
+    (only a batched loop pops one) writes nothing to the result."""
+    n = S.shape[0]
+    lookup = _make_lookup(S, topk_idx)
+    go = st.n_inserted < n
+    f = jnp.argmax(st.gains).astype(jnp.int32)  # vectorized heap-pop
+    v = st.best_v[f]
+    stale = st.inserted[v]
+    face = _face(st, f)
+    slots = jnp.stack([f, st.n_faces, st.n_faces + 1])
+    st = _insert_one(st, f, face, v, S[v, face], write=go & ~stale)
+
+    def refresh(mc, best_v, gains):
         """Lazy re-validation of a popped-stale face (Alg. 2 else-branch)."""
-        face = st.faces[f]
-        mc = st.maxcorr
         for i in range(3):
             mc = mc.at[face[i]].set(lookup(st.inserted, face[i]))
-        v, g = _face_pair(S, mc, face)
-        return st._replace(
-            maxcorr=mc,
-            best_v=st.best_v.at[f].set(v),
-            gains=st.gains.at[f].set(g),
-        )
+        bv, g = _face_pair(S, mc, face)
+        return mc, best_v.at[f].set(bv), gains.at[f].set(g)
 
-    def do_insert(st: _State, f, v):
-        face = st.faces[f]
-        slots = jnp.stack([f, st.n_faces, st.n_faces + 1])
-        st = _insert_one(S, st, f, v)
+    def insert(mc, best_v, gains):
         # refresh maxcorr for the 4 clique vertices (Alg. 2 lines 21–22)
-        mc = st.maxcorr
         for w in (v, face[0], face[1], face[2]):
             mc = mc.at[w].set(lookup(st.inserted, w))
         # compute pairs for the 3 new face slots (Alg. 2 lines 23–25)
-        best_v, gains = st.best_v, st.gains
+        new_faces = _split_face(face, v)
         for i in range(3):
-            bv, g = _face_pair(S, mc, st.faces[slots[i]])
+            bv, g = _face_pair(S, mc, new_faces[3 * i:3 * i + 3])
             best_v = best_v.at[slots[i]].set(bv)
             gains = gains.at[slots[i]].set(g)
-        return st._replace(maxcorr=mc, best_v=best_v, gains=gains)
+        return mc, best_v, gains
 
-    def body(st: _State) -> _State:
-        f = jnp.argmax(st.gains).astype(jnp.int32)  # vectorized heap-pop
-        v = st.best_v[f]
-        stale = st.inserted[v]
-        st = lax.cond(stale, lambda s: refresh(s, f),
-                      lambda s: do_insert(s, f, v), st)
-        return st._replace(pops=st.pops + 1)
+    mc, best_v, gains = lax.cond(stale, refresh, insert,
+                                 st.maxcorr, st.best_v, st.gains)
+    return st._replace(maxcorr=mc, best_v=best_v, gains=gains,
+                       pops=st.pops + go.astype(jnp.int32))
 
-    st = _init_state(S, n)
-    return lax.while_loop(lambda s: s.n_inserted < n, body, st)
+
+@custom_batching.custom_vmap
+def _lazy_loop(S: jax.Array, topk_idx: Optional[jax.Array],
+               st: _State) -> _State:
+    n = S.shape[0]
+    return lax.while_loop(lambda s: s.n_inserted < n,
+                          lambda s: _lazy_pop(S, topk_idx, s), st)
+
+
+@_lazy_loop.def_vmap
+def _lazy_loop_batched(axis_size, in_batched, S, topk_idx, st):
+    """The batched loop pops every build until the last one finishes.
+
+    ``vmap`` of a ``while_loop`` whose predicate differs per build would
+    select the whole carried state, tables included, after every pop; here
+    the predicate is one scalar and a finished build's pops write nothing
+    to its result."""
+    s_b, tk_b, st_b = in_batched
+    st = jax.tree.map(
+        lambda x, b: x if b else jnp.broadcast_to(x, (axis_size,) + x.shape),
+        st, st_b)
+    pop = jax.vmap(_lazy_pop, in_axes=(0 if s_b else None,
+                                       0 if tk_b else None, 0))
+    n = S.shape[-1]
+    st = lax.while_loop(lambda s: jnp.any(s.n_inserted < n),
+                        lambda s: pop(S, topk_idx, s), st)
+    return st, jax.tree.map(lambda _: True, st)
+
+
+def _build_lazy(S: jax.Array, n: int, topk_idx) -> _State:
+    return _lazy_loop(S, topk_idx, _init_state(S, n))
 
 
 # ---------------------------------------------------------------------------
@@ -288,11 +386,13 @@ def _build_corr(S: jax.Array, n: int) -> _State:
         affected = st.best_v == v                      # faces caching v
         affected = affected & (jnp.arange(F) < st.n_faces)
         slots_new = jnp.stack([f, st.n_faces, st.n_faces + 1])
-        st = _insert_one(S, st, f, v)
+        face = _face(st, f)
+        st = _insert_one(st, f, face, v, S[v, face])
         affected = affected.at[slots_new].set(True)
 
         # eager maxcorr refresh for every corner of every affected face
-        corner_rows = jnp.where(affected[:, None], st.faces,
+        faces = st.faces.reshape(F, 3)
+        corner_rows = jnp.where(affected[:, None], faces,
                                 jnp.int32(n))          # n == drop sentinel
         stale_rows = jnp.zeros((n,), bool).at[corner_rows.reshape(-1)].set(
             True, mode="drop")
@@ -300,7 +400,7 @@ def _build_corr(S: jax.Array, n: int) -> _State:
         maxcorr = jnp.where(stale_rows, fresh.astype(jnp.int32), st.maxcorr)
 
         valid = jnp.arange(F) < st.n_faces
-        best_v, gains = _all_face_pairs(S, maxcorr, st.faces, valid)
+        best_v, gains = _all_face_pairs(S, maxcorr, faces, valid)
         best_v = jnp.where(affected, best_v, st.best_v)
         gains = jnp.where(affected, gains, st.gains)
         return st._replace(maxcorr=maxcorr, best_v=best_v, gains=gains,
@@ -320,7 +420,8 @@ def _build_orig(S: jax.Array, n: int, prefix: int) -> _State:
     def round_body(st: _State) -> _State:
         valid = jnp.arange(F) < st.n_faces
         # true best vertex per face: (F, n) masked reduction
-        rows = S[st.faces[:, 0]] + S[st.faces[:, 1]] + S[st.faces[:, 2]]
+        faces = st.faces.reshape(F, 3)
+        rows = S[faces[:, 0]] + S[faces[:, 1]] + S[faces[:, 2]]
         rows = jnp.where(valid[:, None] & ~st.inserted[None, :], rows, NEG)
         per_face_v = jnp.argmax(rows, axis=1).astype(jnp.int32)
         per_face_g = jnp.max(rows, axis=1)
@@ -341,9 +442,9 @@ def _build_orig(S: jax.Array, n: int, prefix: int) -> _State:
             f = top_f[k]
             ok = (jnp.isfinite(top_g[k]) & (st.n_inserted < n)
                   & ~st.inserted[per_face_v[f]])
-            return lax.cond(
-                ok, lambda s: _insert_one(S, s, f, per_face_v[f]),
-                lambda s: s, st)
+            v = per_face_v[f]
+            face = _face(st, f)
+            return _insert_one(st, f, face, v, S[v, face], write=ok)
 
         st = lax.fori_loop(0, prefix, insert_k, st)
         return st._replace(pops=st.pops + 1)
@@ -382,7 +483,7 @@ def build_tmfg(S: jax.Array, *, method: str = "lazy", prefix: int = 10,
         _, topk_idx = lax.top_k(S, k)  # batched over rows: ONE parallel step
 
     if method == "lazy":
-        st = _build_lazy(S, n, _make_lookup(S, topk_idx))
+        st = _build_lazy(S, n, topk_idx)
     elif method == "corr":
         st = _build_corr(S, n)
     elif method == "orig":
@@ -392,13 +493,7 @@ def build_tmfg(S: jax.Array, *, method: str = "lazy", prefix: int = 10,
     else:
         raise ValueError(f"unknown method {method!r}")
 
-    clique = st.insert_order[:4]
-    return TMFGResult(
-        clique=clique, edges=st.edges, faces=st.faces,
-        insert_order=st.insert_order, bubble_verts=st.bubble_verts,
-        bubble_parent=st.bubble_parent, bubble_tri=st.bubble_tri,
-        home_bubble=st.home_bubble, edge_sum=st.edge_sum, pops=st.pops,
-    )
+    return _result(st)
 
 
 @functools.partial(jax.jit, static_argnums=0)

@@ -105,6 +105,33 @@ def test_edge_sum_quality_ordering():
     assert p200 < lazy  # large prefix degrades quality (paper fig. 7)
 
 
+@pytest.mark.parametrize("method,kw", [
+    ("lazy", dict(topk=0)), ("lazy", dict(topk=64)), ("corr", {}),
+    ("orig", dict(prefix=7)),
+])
+def test_vmap_matches_single_builds(method, kw):
+    """``vmap(build_tmfg)`` equals the per-matrix builds field by field,
+    ``pops`` included.  The lazy builds pop stale faces many times and
+    finish after different numbers of pops, so the batched loop keeps
+    popping finished builds, which must write nothing to their results."""
+    n = 60
+    Ss = [clustered_similarity(n, k=k, seed=s)[0]
+          for k, s in ((3, 1), (4, 2), (5, 3), (2, 4), (6, 5))]
+    Ss += [random_symmetric(n, 7), random_symmetric(n, 8)]
+    build = lambda s: build_tmfg(s, method=method, **kw)
+    batched = _np(jax.jit(jax.vmap(build))(np.stack(Ss)))
+    singles = [_np(build(s)) for s in Ss]
+    for field in batched._fields:
+        want = np.stack([getattr(r, field) for r in singles])
+        got = getattr(batched, field)
+        assert got.dtype == want.dtype and got.tobytes() == want.tobytes(), \
+            field
+    if method == "lazy":
+        pops = [int(r.pops) for r in singles]
+        assert min(pops) >= 2 * (n - 4), pops     # many stale pops
+        assert len(set(pops)) > 1, pops           # builds end apart
+
+
 def test_lazy_pops_bounded():
     """Lazy revalidation overhead: pops = n-4 inserts + few stale refreshes."""
     n = 120

@@ -9,6 +9,7 @@ the paper's Crop dataset (n=19,412, L=46) and the hub factor h = ceil(sqrt n).
 """
 
 import os
+import re
 
 import pytest
 
@@ -16,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.tmfg import build_tmfg
 from repro.kernels.gainscan import masked_argmax_pallas
 from repro.kernels.minplus import minplus_pallas
 from repro.kernels.pearson import pearson_pallas
@@ -103,3 +105,71 @@ def test_sparse_relax_compiles_as_xla(shape):
     text = jax.jit(lambda d, g: sparse_relax(d, g, backend="pallas")).lower(
         shape(H, N), graph).compile().as_text()
     assert "tpu_custom_call" not in text
+
+
+_CALLS = re.compile(r"(?:calls|to_apply|body|condition|true_computation"
+                    r"|false_computation)=%([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_MOVE = re.compile(r"^\s*(?:ROOT )?%(\S+) = \(?\w+\[([\d,]*)\]\S*"
+                   r"(?:, \S+)* (copy|copy-start|select)\(")
+
+
+def _table_moves(hlo: str, n: int):
+    """Copies (plain, or an async ``copy-start`` whose output is a tuple)
+    and selects reached from a while loop's body whose output has the
+    shape of one of the TMFG tables, (F, 3) faces, (E, 2) edges, (B, 4)
+    and (B, 3) bubbles, 2-D or flat, batched or not."""
+    F, E, B = 2 * n - 4, 3 * n - 6, n - 3
+    tables = {(F, 3), (E, 2), (B, 4), (B, 3)}
+    tables |= {(r * k,) for r, k in tables}
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        if line.startswith(("%", "ENTRY")) and line.rstrip().endswith("{"):
+            name = line.split()[line.startswith("ENTRY")].lstrip("%")
+            comps[name] = []
+        elif name is not None and line.startswith("  "):
+            comps[name].append(line)
+
+    def callees(line):
+        out = _CALLS.findall(line)
+        for group in _BRANCHES.findall(line):
+            out += [c.strip().lstrip("%") for c in group.split(",")]
+        return out
+
+    todo = [m for lines in comps.values() for line in lines
+            if " while(" in line
+            for m in re.findall(r"body=%([\w.\-]+)", line)]
+    assert todo, "no while loop in the program"
+    seen, moves = set(), []
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            todo += callees(line)
+            m = _MOVE.match(line)
+            if m:
+                dims = tuple(int(d) for d in m.group(2).split(",") if d)
+                if any(dims[-len(t):] == t for t in tables):
+                    moves.append(f"{c}: {m.group(3)} %{m.group(1)} {dims}")
+    return moves
+
+
+@pytest.mark.parametrize("batch,n", [(None, 9236), (64, 500)])
+def test_lazy_tmfg_loop_moves_no_table(shape, batch, n):
+    """The lazy loop updates its carried tables in place: no copy and no
+    select of a whole table per pop, alone (StarLightCurves' n) or under
+    ``vmap`` (a backtest batch).  A table carried through a ``lax.cond``
+    is copied out of the branch, and a batched ``while_loop`` selects
+    every table after every pop; on this chip an (N, <=4) int table pads
+    its minor dimension to 128 lanes, so each such move costs 32-64x the
+    table.  A table staged between memory spaces on every pop (an async
+    copy pair) is a move too."""
+    build = lambda s: build_tmfg(s, method="lazy", topk=64)
+    if batch is None:
+        fn, arg = build, shape(n, n)
+    else:
+        fn, arg = jax.vmap(build), shape(batch, n, n)
+    hlo = jax.jit(fn).lower(arg).compile().as_text()
+    assert _table_moves(hlo, n) == []
