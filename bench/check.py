@@ -1,6 +1,12 @@
 """Whether the timed path's answers are correct: a sample of them, drawn
-from the seed, against the plain reference (``reference.py``) run on the
+from the seed, against the configuration's plain reference run on the
 same inputs.
+
+The reference is the configuration's: ``"reference": "<name>"`` in its
+file names ``bench/reference_<name>.py``, whose ``answer(X, k,
+similarity, config=...)`` gives (TMFG, labels) and whose ``CONTROLS``
+name the similarities a control plants; without the key it is the dense
+reference, ``reference.py`` through :func:`reference_answer`.
 
 Two numbers are compared, each the worst over the sampled problems, each
 against the limit the configuration file gives it:
@@ -16,13 +22,19 @@ against the limit the configuration file gives it:
 
 from __future__ import annotations
 
+import functools
+import importlib.util
+import re
 import sys
-from typing import Callable, Dict, List
+from pathlib import Path
+from types import SimpleNamespace
+from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
 import reference as ref
 
+BENCH = Path(__file__).resolve().parent
 NUMBERS = ("tmfg_unshared", "ari_gap")
 
 
@@ -49,6 +61,32 @@ def reference_answer(X: np.ndarray, k: int, similarity=ref.pearson):
     return tm, ref.dbht_labels(S, tm, k)
 
 
+# the dense reference's controls: its similarity one step below the
+# configurations' float32 (bf16), and the two above it for comparison
+CONTROLS = {"bf16": ref.pearson_bf16, "3pass": ref.pearson_3pass,
+            "f32": ref.pearson_f32}
+
+
+def reference(config: dict) -> SimpleNamespace:
+    """The configuration's plain reference: ``answer(X, k[, similarity])``
+    -> (TMFG, labels), and ``controls``, the similarities a control puts
+    in place of the reference's own, by name.  A named reference with no
+    module raises."""
+    name = config.get("reference")
+    if name is None:
+        return SimpleNamespace(answer=reference_answer, controls=CONTROLS)
+    path = BENCH / f"reference_{name}.py"
+    if not re.fullmatch(r"[A-Za-z0-9_]+", str(name)) or not path.is_file():
+        raise ValueError(f"the configuration names reference {name!r}, "
+                         f"and there is no bench/reference_{name}.py")
+    mod_spec = importlib.util.spec_from_file_location(
+        f"bench_reference_{name}", path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return SimpleNamespace(answer=functools.partial(mod.answer, config=config),
+                           controls=mod.CONTROLS)
+
+
 def sample(answers: List, count: int, seed: int) -> List:
     if len(answers) <= count:
         return list(answers)
@@ -58,13 +96,15 @@ def sample(answers: List, count: int, seed: int) -> List:
 
 
 def run_checks(answers: List, inputs: Callable, k: int, count: int,
-               seed: int) -> Dict[str, float]:
+               seed: int, config: Optional[dict] = None) -> Dict[str, float]:
     """The worst of each number over a seeded sample of ``count`` answers
-    (every number reads 1 when no answer came back)."""
+    against ``config``'s reference (every number reads 1 when no answer
+    came back)."""
+    answer = reference(config or {}).answer
     worst = dict.fromkeys(NUMBERS, 0.0) if answers else \
         dict.fromkeys(NUMBERS, 1.0)
     for a in sample(answers, count, seed):
-        tm, labels = reference_answer(inputs(a.source), k)
+        tm, labels = answer(inputs(a.source), k)
         got = compare(a.edges, a.labels, tm, labels)
         for name in NUMBERS:
             worst[name] = max(worst[name], got[name])

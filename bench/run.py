@@ -152,7 +152,7 @@ def main(argv=None) -> int:
     import check
 
     worst = check.run_checks(run.answers, run.inputs, int(config["k"]),
-                             int(traffic["checked"]), args.seed)
+                             int(traffic["checked"]), args.seed, config)
     result["correct"], result["checks"] = check.verdict(
         worst, config["limits"])
     line = {"rehearsal": result} if args.rehearse else result
