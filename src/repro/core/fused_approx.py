@@ -59,6 +59,7 @@ from repro.approx.sparse_tmfg import SparseCounters, sparse_lazy_tmfg
 from repro.kernels import ops
 from repro.kernels.ref import standardize_rows
 from repro.kernels.sparse_apsp import CSRGraph, csr_from_edges
+from repro.obs import trace as obs_trace
 from .tmfg import TMFGResult, adjacency_from_weights, build_tmfg
 
 INF = jnp.inf
@@ -414,12 +415,14 @@ def _assemble_device(n: int, all_rows, members, counts_perm, perm, Zt,
 
 
 def _sparse_tail(cfg, n: int, tm: TMFGResult, w_sim, c_cap: int,
-                 m_cap: int, bm: int):
+                 m_cap: int, bm: int, mark):
     """TMFG edge list + per-edge similarities → sparse DBHT outputs.
 
     The traceable form of ``sparse_dbht.dbht_sparse``'s device stages;
     returns a dict matching ``dbht._dbht_device_core``'s plus
-    (hubs, overflow, apsp_rounds)."""
+    (hubs, overflow, apsp_rounds).  ``mark``, an ``obs.trace.StageMarks``,
+    marks the APSP/DBHT and DBHT/HAC boundaries; the panel sweep is the
+    HAC's."""
     from repro.core import dbht as dbht_mod  # local: no import cycle
     from repro.core.sparse_dbht import PANEL_ROWS  # noqa: F401
 
@@ -431,6 +434,7 @@ def _sparse_tail(cfg, n: int, tm: TMFGResult, w_sim, c_cap: int,
     with jax.named_scope("apsp"):
         hubs, D_h, rounds = apsp_mod.hub_factor_sparse_rounds(
             graph, cfg.apsp_hubs, cfg.apsp_rounds)
+    D_h, tm, w_sim = mark("apsp", (D_h, tm, w_sim))
 
     with jax.named_scope("dbht"):
         direction = _device_directions_sparse(
@@ -441,12 +445,15 @@ def _sparse_tail(cfg, n: int, tm: TMFGResult, w_sim, c_cap: int,
         conv_id = jnp.cumsum(conv_mask.astype(jnp.int32)) - 1
         bubble_cluster = conv_id[dest]
         cluster_of = bubble_cluster[tm.home_bubble.astype(jnp.int32)]
+    bubble_cluster, cluster_of = mark("dbht", (bubble_cluster, cluster_of))
 
+    with jax.named_scope("hac"):
+        # the composed-distance sweep gives the bubbles (DBHT's last
+        # output) and the HAC's scale and cross-cluster maxima at once
         bubble_of, dmax, ccm = _sweep_panels_device(
             D_h, graph, tm.bubble_verts, bubble_cluster, cluster_of, c_cap,
             bm)
 
-    with jax.named_scope("hac"):
         m1 = jnp.float32(2.0) * dmax                         # oracle's f32
         m2 = jnp.float32(8.0) * dmax
         off2 = m2 - m1
@@ -491,10 +498,10 @@ def _sparse_tail(cfg, n: int, tm: TMFGResult, w_sim, c_cap: int,
 # the fused one-matrix body (dense/sparse dispatch is trace-time)
 # ---------------------------------------------------------------------------
 
-def _dense_tail(cfg, S, tm: TMFGResult):
+def _dense_tail(cfg, S, tm: TMFGResult, mark):
     """The dense formulation — exactly ``pipeline._fused_one``'s tail,
     shared by the approx configs whose staged path is dense (exact APSP
-    below HUB_MIN_N, or non-hub methods)."""
+    below HUB_MIN_N, or non-hub methods), with its stage marks."""
     from repro.core import dbht as dbht_mod
 
     with jax.named_scope("apsp"):
@@ -502,9 +509,10 @@ def _dense_tail(cfg, S, tm: TMFGResult):
         D, rounds = apsp_mod.apsp_rounds(
             W, method=cfg.apsp_method, n_hubs=cfg.apsp_hubs,
             rounds=cfg.apsp_rounds, backend=cfg.backend)
+    S, tm, D = mark("apsp", (S, tm, D))
     core = dbht_mod._dbht_device_core(
         S, tm.edges, tm.bubble_parent, tm.bubble_tri, tm.bubble_verts,
-        tm.home_bubble, D, backend=cfg.backend)
+        tm.home_bubble, D, backend=cfg.backend, mark=mark)
     core["hubs"] = None
     core["overflow"] = None
     core["apsp_rounds"] = rounds
@@ -544,31 +552,38 @@ def fused_from_table(cfg, n: int, *, from_x: bool = True,
             f"composite sort keys); got n={n}")
     c_cap, m_cap = fused_caps(n, caps)
     sparse = use_sparse_tail(cfg, n)
+    mark = obs_trace.StageMarks(False)
 
     def tail(tv, ti, src):
         with jax.named_scope("tmfg"):
             tm, w_edges, counters = sparse_lazy_tmfg(tv, ti, src,
                                                      from_x=from_x)
         if sparse:
-            core = _sparse_tail(cfg, n, tm, w_edges, c_cap, m_cap, bm)
+            core = _sparse_tail(cfg, n, tm, w_edges, c_cap, m_cap, bm,
+                                mark)
         else:
             S_use = adjacency_from_weights(n, tm.edges, w_edges) \
                 if from_x else src
-            core = _dense_tail(cfg, S_use, tm)
+            core = _dense_tail(cfg, S_use, tm, mark)
         return _with_counters(core, n, tm, counters)
 
     return tail
 
 
 def fused_one(cfg, have_S: bool, n: int,
-              caps: Optional[Tuple[int, int]] = None, bm: int = 512):
+              caps: Optional[Tuple[int, int]] = None, bm: int = 512,
+              marks: bool = False):
     """The traceable single-matrix approx/sparse pipeline body.
 
     The counterpart of ``pipeline._fused_one`` for the configs it used
     to reject: ``similarity="topk"`` (any APSP method) and dense
     similarity with ``apsp_method="sparse"``.  Returns a function
     ``one(arr) -> dict`` with the ``_dbht_device_core`` keys plus
-    (tmfg, hubs, overflow, counters)."""
+    (tmfg, hubs, overflow, counters).  With ``marks`` each boundary of
+    ``pipeline.STAGES`` is also a host-clock mark (DESIGN.md §15.5):
+    the top-K table (or the similarity) ends ``similarity``, and the
+    sweep, the slot grid, the top-level HAC and the assembly make up
+    ``hac``."""
     if n > FUSED_MAX_N:
         raise ValueError(
             f"fused approx path supports n <= {FUSED_MAX_N} (int32 "
@@ -579,16 +594,20 @@ def fused_one(cfg, have_S: bool, n: int,
     sparse = use_sparse_tail(cfg, n)
 
     def one(arr):
+        mark = obs_trace.StageMarks(marks)
+        arr = mark("start", arr)
         counters = None
         if not approx:
             # dense similarity + sparse APSP tail (§14.6 retired)
             with jax.named_scope("similarity"):
                 S = arr if have_S else ops.pearson(arr, backend=cfg.backend)
+            S = mark("similarity", S)
             with jax.named_scope("tmfg"):
                 tm = build_tmfg(S, method=cfg.method, prefix=cfg.prefix,
                                 topk=cfg.topk)
             w_sim = S[tm.edges[:, 0], tm.edges[:, 1]]
-            core = _sparse_tail(cfg, n, tm, w_sim, c_cap, m_cap, bm)
+            tm, w_sim = mark("tmfg", (tm, w_sim))
+            core = _sparse_tail(cfg, n, tm, w_sim, c_cap, m_cap, bm, mark)
         else:
             kk = min(cfg.sim_k, n - 1)
             with jax.named_scope("topk"):
@@ -604,31 +623,35 @@ def fused_one(cfg, have_S: bool, n: int,
                                       bm=128, bn=128)
                     src, from_x = standardize_rows(arr), True
                     S = None
+            tv, ti, src = mark("similarity", (tv, ti, src))
             if cfg.method == "lazy":
                 with jax.named_scope("tmfg"):
                     tm, w_edges, counters = sparse_lazy_tmfg(
                         tv, ti, src, from_x=from_x)
+                tm, w_edges = mark("tmfg", (tm, w_edges))
                 if sparse:
                     core = _sparse_tail(cfg, n, tm, w_edges, c_cap,
-                                        m_cap, bm)
+                                        m_cap, bm, mark)
                 else:
                     # staged: real S from a window, else the weighted
                     # adjacency scattered from the recorded edges
                     S_use = S if S is not None else \
                         adjacency_from_weights(n, tm.edges, w_edges)
-                    core = _dense_tail(cfg, S_use, tm)
+                    core = _dense_tail(cfg, S_use, tm, mark)
             else:
                 # non-lazy methods run on the densified table (§13.3)
                 with jax.named_scope("tmfg"):
                     Sd = _densify(tv, ti, n)
                     tm = build_tmfg(Sd, method=cfg.method,
                                     prefix=cfg.prefix, topk=cfg.topk)
+                Sd, tm = mark("tmfg", (Sd, tm))
                 if sparse:
                     w_sim = Sd[tm.edges[:, 0], tm.edges[:, 1]]
                     core = _sparse_tail(cfg, n, tm, w_sim, c_cap,
-                                        m_cap, bm)
+                                        m_cap, bm, mark)
                 else:
-                    core = _dense_tail(cfg, Sd, tm)
+                    core = _dense_tail(cfg, Sd, tm, mark)
+        core["Z"] = mark("hac", core["Z"])
         return _with_counters(core, n, tm, counters)
 
     return one

@@ -78,7 +78,7 @@ def _observe_total(path: str, seconds: float) -> None:
 
 
 # the fused path's host phases (DESIGN.md §15.5), in call order, and the
-# stage marks of the dense body when built under tracing
+# stage marks of the TMFG bodies when built under tracing
 PHASES = ("put", "dispatch", "device", "assemble")
 STAGES = ("similarity", "tmfg", "apsp", "dbht", "hac")
 
@@ -307,11 +307,12 @@ def _fused_filter_one(cfg: PipelineConfig, have_S: bool):
     return one
 
 
-def _fused_approx_one(cfg: PipelineConfig, have_S: bool, n: int, caps):
+def _fused_approx_one(cfg: PipelineConfig, have_S: bool, n: int, caps,
+                      marks: bool):
     """The §17 body wrapped into the :class:`DeviceOutputs` pytree."""
     from repro.core import fused_approx as fa  # lazy: keeps import light
 
-    raw = fa.fused_one(cfg, have_S, n, caps=caps)
+    raw = fa.fused_one(cfg, have_S, n, caps=caps, marks=marks)
 
     def one(arr):
         core = raw(arr)
@@ -352,8 +353,8 @@ def run_pipeline_device(X_or_S, config: PipelineConfig, *,
     divide the mesh).
 
     While tracing is on (``obs.trace.enable``/``tracing``) when the
-    program is built, the dense body also takes a host-clock mark at
-    each stage boundary (DESIGN.md §15.5).
+    program is built, a TMFG body (dense or approx) also takes a
+    host-clock mark at each stage boundary (DESIGN.md §15.5).
 
     Returns :class:`DeviceOutputs` — device arrays, NO host transfer:
     callers choose what crosses the boundary (``cluster`` transfers
@@ -398,15 +399,14 @@ def run_pipeline_device(X_or_S, config: PipelineConfig, *,
     # stage marks are host callbacks, and JAX writes no persistent-cache
     # entry for a program with one: only a program built while tracing
     # is on carries them, under a key of its own (DESIGN.md §15.5)
-    dense = config.filter == "tmfg" and not _needs_approx_body(config)
-    marks = dense and obs_trace.enabled()
+    marks = config.filter == "tmfg" and obs_trace.enabled()
 
     def build():
         if config.filter != "tmfg":
             one = _fused_filter_one(config, is_similarity)
-        elif not dense:
+        elif _needs_approx_body(config):
             one = _fused_approx_one(config, is_similarity,
-                                    int(arr.shape[-2]), caps)
+                                    int(arr.shape[-2]), caps, marks)
         else:
             one = _fused_one(config, is_similarity, marks)
         if not batched:

@@ -6,6 +6,9 @@ Each function is the semantic ground truth its kernel is tested against
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -38,10 +41,23 @@ def standardize_rows(X: jnp.ndarray, eps: float = 1e-12) -> jnp.ndarray:
     return Z / denom
 
 
+def pearson_rows(Zr: jnp.ndarray, Z: jnp.ndarray) -> jnp.ndarray:
+    """Pearson rows (m, n) of the standardized rows ``Zr (m, L)`` against
+    ``Z (n, L)``: the one arithmetic of the dense matrix and of every
+    row panel of the jnp top-K scan."""
+    return jnp.clip(jnp.dot(Zr, Z.T, precision=SIM_PRECISION), -1.0, 1.0)
+
+
+@functools.partial(jax.jit, static_argnames=("eps",))
 def pearson_ref(X: jnp.ndarray, eps: float = 1e-12) -> jnp.ndarray:
-    """Pearson correlation matrix of the rows of X (n, L) -> (n, n)."""
+    """Pearson correlation matrix of the rows of X (n, L) -> (n, n).
+
+    Compiled whole, also where it is called outside a jit (the staged
+    path): run op by op, its dot would read a materialized ``Z.T`` and
+    round some entries one ulp away from the compiled form that the
+    fused programs and the top-K scan's panels use."""
     Z = standardize_rows(X, eps)
-    return jnp.clip(jnp.dot(Z, Z.T, precision=SIM_PRECISION), -1.0, 1.0)
+    return pearson_rows(Z, Z)
 
 
 def masked_argmax_ref(S: jnp.ndarray, mask: jnp.ndarray):

@@ -12,8 +12,8 @@ peak memory is ``O(n·K + n·L)`` instead of ``O(n²)``.
 Tie semantics match ``jax.lax.top_k`` on the dense matrix exactly:
 values descending, equal values ordered by ascending column index.
 The diagonal (self-correlation) is excluded.  The jnp fallback
-computes each ``(bm, n)`` row-panel with the same
-``standardize → clip(Z @ Z.T)`` arithmetic as ``ref.pearson_ref``, so
+computes each ``(bm, n)`` row-panel with ``ref.pearson_rows``, the
+arithmetic of ``ref.pearson_ref``, so
 at ``K = n-1`` the candidate table holds bit-identical values to the
 dense similarity matrix's rows (the exactness contract
 tests/test_approx.py pins end to end).
@@ -28,7 +28,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from .ref import SIM_PRECISION, standardize_rows
+from .ref import SIM_PRECISION, pearson_rows, standardize_rows
 
 NEG = -3.4e38  # finite -inf stand-in (kernel-internal; Pearson ∈ [-1, 1])
 
@@ -170,9 +170,9 @@ def topk_rows_jnp(Zr: jax.Array, Z: jax.Array, rid: jax.Array, k: int, *,
                   bm: int = 128):
     """``topk_rows_pallas`` in pure XLA (the CPU production path).
 
-    Scans ``(bm, n)`` row-panels — ``clip(Zr[panel] @ Z.T)``, exactly
-    ``ref.pearson_ref``'s arithmetic, which XLA computes bit-identically
-    to the corresponding rows of the full matmul — and reduces each to
+    Scans ``(bm, n)`` row-panels — ``ref.pearson_rows``, the arithmetic
+    of ``ref.pearson_ref``, whose rows XLA computes bit-identically to
+    the corresponding rows of the full matmul — and reduces each to
     its per-row ``lax.top_k``.  Peak live memory is the panel plus the
     (m, k) outputs; the (n, n) matrix never exists (the jaxpr shape
     check in tests/test_approx.py pins this).
@@ -187,8 +187,7 @@ def topk_rows_jnp(Zr: jax.Array, Z: jax.Array, rid: jax.Array, k: int, *,
 
     def panel(_, i0):
         rows = lax.dynamic_slice(Zrp, (i0, 0), (bm_, L))
-        s = jnp.clip(jnp.dot(rows, Z.T, precision=SIM_PRECISION),
-                     -1.0, 1.0)                              # (bm, n)
+        s = pearson_rows(rows, Z)                            # (bm, n)
         r = lax.dynamic_slice(ridp, (i0,), (bm_,))
         s = jnp.where(r[:, None] == jnp.arange(n)[None, :], -jnp.inf, s)
         v, ix = lax.top_k(s, k)

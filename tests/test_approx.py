@@ -212,7 +212,8 @@ def test_topk_kernel_peak_is_one_panel():
 @pytest.mark.parametrize("n,L,k", [(30, 40, 7), (48, 64, 47), (130, 33, 16)])
 def test_topk_table_matches_dense_topk(n, L, k):
     """ops.topk (jnp) == lax.top_k of the dense matrix — indices exact
-    (including tie order), values bitwise."""
+    (including tie order), values bitwise: the scan's panels and the
+    dense matrix are one compiled arithmetic (``ref.pearson_rows``)."""
     rng = np.random.default_rng(n)
     X = rng.normal(size=(n, L)).astype(np.float32)
     S = pearson_ref(jnp.asarray(X))

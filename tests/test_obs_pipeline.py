@@ -13,7 +13,7 @@ Pins:
     persistent-cache entry is written), the one built under
     ``tracing()`` has, under a key of its own, and its stage marks sum
     to no more than the call's time on the device and fire once per
-    stage for a whole batch;
+    stage for a whole batch; the approx body's too, on either tail;
   * an ``obs.trace.span`` is an annotation of a profiler session.
 """
 
@@ -376,6 +376,49 @@ def test_stage_marks_fire_once_per_stage_for_a_batch():
         marks = obs_trace.take_marks()
     assert [m[0] for m in marks] == ["start", *STAGES]
     assert all(b[1] >= a[1] for a, b in zip(marks, marks[1:]))
+
+
+@pytest.mark.parametrize("n", [40, 600])       # the dense tail; the sparse
+def test_approx_marks_only_in_the_program_built_under_tracing(n):
+    """The approx body takes the same five marks on either tail, only in
+    the program built under tracing; with tracing off it is built under
+    the key it always had, with no callback, and the marks change when it
+    runs, not what it computes."""
+    from repro.core import fused_approx as fa
+
+    X = make_dataset(n, 46, 6, noise=0.7, seed=12)[0]
+    cfg = PipelineConfig.approx(sim_k=16)
+    assert fa.use_sparse_tail(cfg, n) == (n >= apsp_mod.HUB_MIN_N)
+    shape = (n, 46)
+    res_off = cluster(X, k=3, config=cfg)
+    assert "stages" not in last_call().attrs
+    with obs_trace.tracing():
+        res_on = cluster(X, k=3, config=cfg)
+    attrs = last_call().attrs
+    stages = attrs["stages"]
+    assert list(stages) == list(STAGES)
+    assert all(s >= 0.0 for s in stages.values())
+    # the CPU runs a program with host callbacks inside its dispatch
+    assert 0.0 < sum(stages.values()) <= attrs["dispatch_s"] \
+        + attrs["device_s"]
+
+    keys = [k for k in fused_keys(shape) if k[1] == cfg]
+    off = [k for k in keys if k[-1] is False]
+    on = [k for k in keys if k[-1] is True]
+    assert off == [("fused", cfg, False, False, shape, None, None, False)]
+    assert on == [off[0][:-1] + (True,)]
+
+    def hlo(key):
+        fn = jitcache.cached(key, None)
+        return fn.lower(jax.ShapeDtypeStruct(shape, jnp.float32)).as_text()
+
+    assert "callback" not in hlo(off[0])
+    assert "callback" in hlo(on[0])
+    np.testing.assert_array_equal(res_on.tmfg.edges, res_off.tmfg.edges)
+    np.testing.assert_array_equal(res_on.labels, res_off.labels)
+    np.testing.assert_array_equal(res_on.linkage, res_off.linkage)
+    np.testing.assert_array_equal(res_on.dbht.apsp, res_off.dbht.apsp)
+    assert not res_on.overflow and not res_off.overflow
 
 
 def test_persistent_cache_written_only_without_marks(tmp_path):
